@@ -9,7 +9,6 @@ Prints ``name,value,derived`` CSV.  Figures:
   shard  sharded multi-object runtime       bench_sharded (smoke grid)
   reshard  split/merge before-during-after  bench_reshard (smoke grid)
   phase_loop  fused K-phase dispatch        bench_phase_loop (smoke grid)
-  roofline  per-cell fractions (from dry-run artifacts, if present)
 
 The bench story (what each module measures, the BENCH_*.json schema) is
 documented in docs/benchmarks.md.
@@ -61,12 +60,6 @@ def main() -> None:
                 extra={"entry": "run.py", "smoke": True},
             )
             print(f"# wrote {out} ({len(rows)} configs)", file=sys.stderr)
-    try:
-        from benchmarks import roofline
-
-        roofline.main(emit)
-    except Exception as e:  # dry-run artifacts may be absent on fresh checkouts
-        print(f"# roofline skipped: {e!r}", file=sys.stderr)
     print(f"# total {time.time()-t0:.1f}s", file=sys.stderr)
 
 

@@ -31,10 +31,9 @@ from pathlib import Path
 
 import numpy as np
 
-import jax
-
 from repro.checkpoint.dfc_checkpoint import SimFS
 from repro.core.jax_dfc import STRUCTS
+from repro.launch.compile_cache import use_compile_cache
 from repro.runtime.announce_driver import MultiThreadDriver
 from repro.runtime.dfc_shard import (
     R_OVERFLOW,
@@ -63,7 +62,7 @@ def main():
                          "op count by N (0 = never)")
     args = ap.parse_args()
 
-    jax.config.update("jax_platform_name", "cpu")
+    use_compile_cache()
     rng = np.random.default_rng(0)
     all_kinds = sorted(STRUCTS)
     kinds = (

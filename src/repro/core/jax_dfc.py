@@ -164,10 +164,12 @@ def init_stack(capacity: int, dtype=jnp.float32) -> StackState:
 def _onehot_route(src_idx: jax.Array, vals: jax.Array, n_out: int) -> jax.Array:
     """out[src_idx[j]] += vals[j] — as a one-hot matmul (MXU-friendly).
 
-    src_idx entries outside [0, n_out) are dropped.
+    src_idx entries outside [0, n_out) are dropped.  HIGHEST precision keeps
+    f32 payloads exact on the TPU, whose default f32 matmul rounds inputs
+    to bf16.
     """
     onehot = (src_idx[None, :] == jnp.arange(n_out)[:, None]).astype(vals.dtype)
-    return onehot @ vals
+    return jnp.dot(onehot, vals, precision=jax.lax.Precision.HIGHEST)
 
 
 def combine(

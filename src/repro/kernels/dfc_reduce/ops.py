@@ -1,18 +1,22 @@
 """Jitted public wrappers: full DFC combine steps using the Pallas kernels.
 
 Splice the kernel outputs (responses / surplus segments / counts) into the
-array-backed double-buffered structure states (stack, queue, deque).
-``backend`` selects the Pallas kernel (compiled for TPU via ``pallas_tpu``,
-interpret-mode via ``pallas``) or the pure-jnp oracle (``ref``).
+array-backed double-buffered structure states (stack, queue, deque, map).
+``backend`` is one of :data:`BACKENDS`: ``jnp`` vmaps the vectorized
+combine of ``repro.core.jax_dfc``, ``ref`` vmaps the pure-jnp twin of the
+kernels (a test oracle), and ``pallas`` runs the Pallas kernels — compiled
+for the chip, or in the Pallas interpreter when the platform is the CPU
+(``kernel.default_interpret``).
 
-Each structure factors into a window builder (read the committed end(s) of
-the array into the kernel's lane-sized window) and a splice (apply the
-kernel's surplus segments/counts back to the double-buffered state with an
-epoch bump of +2).  The sharded steps (``dfc_sharded_*_combine_step``) vmap
-the builder and the splice over a leading shard axis and run ALL shards'
-combining phases in one Pallas grid dispatch (grid=(S,), one program
-instance per shard) — the multi-object amortization the sharded runtime
-(`repro.runtime.dfc_shard`) is built on.
+Each ring structure factors into a window builder (read the committed
+end(s) of the array into the kernel's lane-sized window) and a splice
+(apply the kernel's surplus segments/counts back to the double-buffered
+state with an epoch bump of +2).  The sharded steps
+(``dfc_sharded_*_combine_step``) vmap the builder and the splice over a
+leading shard axis and run ALL shards' combining phases in one Pallas grid
+dispatch (grid=(S,), one program instance per shard) — the multi-object
+amortization the sharded runtime (`repro.runtime.dfc_shard`) is built on.
+The single-object steps are the sharded steps over a one-shard stack.
 """
 
 from __future__ import annotations
@@ -21,9 +25,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.jax_dfc import (
     OP_NONE,
@@ -34,12 +35,9 @@ from repro.core.jax_dfc import (
     StackState,
 )
 from repro.kernels.dfc_reduce.kernel import (
-    dfc_deque_reduce_call,
     dfc_deque_reduce_grid_call,
     dfc_map_reduce_grid_call,
-    dfc_queue_reduce_call,
     dfc_queue_reduce_grid_call,
-    dfc_reduce_call,
     dfc_reduce_grid_call,
 )
 from repro.kernels.dfc_reduce.ref import (
@@ -48,6 +46,17 @@ from repro.kernels.dfc_reduce.ref import (
     dfc_queue_reduce_ref,
     dfc_reduce_ref,
 )
+
+BACKENDS = ("jnp", "ref", "pallas")
+
+
+def _kernel_or_ref(backend: str, kernel, ref, *args):
+    """Run the Pallas grid ``kernel`` or the vmapped pure-jnp ``ref`` twin."""
+    if backend == "pallas":
+        return kernel(*args)
+    if backend == "ref":
+        return jax.vmap(ref)(*args)
+    raise ValueError(f"backend {backend!r} is not one of {BACKENDS}")
 
 
 # ------------------------------------------------------------------- stack
@@ -85,24 +94,6 @@ def _stack_splice(state: StackState, segment, counts) -> StackState:
     )
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def dfc_combine_step(state: StackState, ops, params, *, backend: str = "ref"):
-    window, old_size = _stack_window(state, ops.shape[0])
-
-    if backend == "pallas":
-        resp, kinds, segment, counts = dfc_reduce_call(
-            ops, params, window, old_size, interpret=True
-        )
-    elif backend == "pallas_tpu":
-        resp, kinds, segment, counts = dfc_reduce_call(
-            ops, params, window, old_size, interpret=False
-        )
-    else:
-        resp, kinds, segment, counts = dfc_reduce_ref(ops, params, window, old_size)
-
-    return _stack_splice(state, segment, counts), resp, kinds
-
-
 # ------------------------------------------------------------------- queue
 def _queue_window(state: QueueState, n: int):
     """Front window: queue[head : head+n], zero-padded past the tail."""
@@ -133,25 +124,6 @@ def _queue_splice(state: QueueState, segment, counts) -> QueueState:
         ends=state.ends.at[inactive].set(new_ends),
         epoch=state.epoch + 2,
     )
-
-
-@functools.partial(jax.jit, static_argnames=("backend",))
-def dfc_queue_combine_step(state: QueueState, ops, params, *, backend: str = "ref"):
-    """Queue combine phase: front window -> kernel -> masked ring splice."""
-    window, size = _queue_window(state, ops.shape[0])
-
-    if backend == "pallas":
-        resp, kinds, segment, counts = dfc_queue_reduce_call(
-            ops, params, window, size, interpret=True
-        )
-    elif backend == "pallas_tpu":
-        resp, kinds, segment, counts = dfc_queue_reduce_call(
-            ops, params, window, size, interpret=False
-        )
-    else:
-        resp, kinds, segment, counts = dfc_queue_reduce_ref(ops, params, window, size)
-
-    return _queue_splice(state, segment, counts), resp, kinds
 
 
 # ------------------------------------------------------------------- deque
@@ -192,27 +164,6 @@ def _deque_splice(state: DequeState, seg_l, seg_r, counts) -> DequeState:
     )
 
 
-@functools.partial(jax.jit, static_argnames=("backend",))
-def dfc_deque_combine_step(state: DequeState, ops, params, *, backend: str = "ref"):
-    """Deque combine phase: end windows -> two-sided kernel -> ring splices."""
-    window_l, window_r, size = _deque_windows(state, ops.shape[0])
-
-    if backend == "pallas":
-        resp, kinds, seg_l, seg_r, counts = dfc_deque_reduce_call(
-            ops, params, window_l, window_r, size, interpret=True
-        )
-    elif backend == "pallas_tpu":
-        resp, kinds, seg_l, seg_r, counts = dfc_deque_reduce_call(
-            ops, params, window_l, window_r, size, interpret=False
-        )
-    else:
-        resp, kinds, seg_l, seg_r, counts = dfc_deque_reduce_ref(
-            ops, params, window_l, window_r, size
-        )
-
-    return _deque_splice(state, seg_l, seg_r, counts), resp, kinds
-
-
 # ----------------------------------------------------------------- sharded
 # All shards' combining phases in one dispatch.  States are shard-stacked
 # pytrees (leading S axis on every leaf, see ``repro.core.jax_dfc``); ops and
@@ -220,22 +171,10 @@ def dfc_deque_combine_step(state: DequeState, ops, params, *, backend: str = "re
 @functools.partial(jax.jit, static_argnames=("backend",))
 def dfc_sharded_combine_step(state: StackState, ops, params, *, backend: str = "ref"):
     """Sharded stack combine: one grid dispatch, program instance = shard."""
-    n = ops.shape[1]
-    windows, sizes = jax.vmap(_stack_window, in_axes=(0, None))(state, n)
-
-    if backend == "pallas":
-        resp, kinds, segments, counts = dfc_reduce_grid_call(
-            ops, params, windows, sizes, interpret=True
-        )
-    elif backend == "pallas_tpu":
-        resp, kinds, segments, counts = dfc_reduce_grid_call(
-            ops, params, windows, sizes, interpret=False
-        )
-    else:
-        resp, kinds, segments, counts = jax.vmap(dfc_reduce_ref)(
-            ops, params, windows, sizes
-        )
-
+    windows, sizes = jax.vmap(_stack_window, in_axes=(0, None))(state, ops.shape[1])
+    resp, kinds, segments, counts = _kernel_or_ref(
+        backend, dfc_reduce_grid_call, dfc_reduce_ref, ops, params, windows, sizes
+    )
     return jax.vmap(_stack_splice)(state, segments, counts), resp, kinds
 
 
@@ -244,22 +183,11 @@ def dfc_sharded_queue_combine_step(
     state: QueueState, ops, params, *, backend: str = "ref"
 ):
     """Sharded queue combine: one grid dispatch, program instance = shard."""
-    n = ops.shape[1]
-    windows, sizes = jax.vmap(_queue_window, in_axes=(0, None))(state, n)
-
-    if backend == "pallas":
-        resp, kinds, segments, counts = dfc_queue_reduce_grid_call(
-            ops, params, windows, sizes, interpret=True
-        )
-    elif backend == "pallas_tpu":
-        resp, kinds, segments, counts = dfc_queue_reduce_grid_call(
-            ops, params, windows, sizes, interpret=False
-        )
-    else:
-        resp, kinds, segments, counts = jax.vmap(dfc_queue_reduce_ref)(
-            ops, params, windows, sizes
-        )
-
+    windows, sizes = jax.vmap(_queue_window, in_axes=(0, None))(state, ops.shape[1])
+    resp, kinds, segments, counts = _kernel_or_ref(
+        backend, dfc_queue_reduce_grid_call, dfc_queue_reduce_ref,
+        ops, params, windows, sizes,
+    )
     return jax.vmap(_queue_splice)(state, segments, counts), resp, kinds
 
 
@@ -268,23 +196,42 @@ def dfc_sharded_deque_combine_step(
     state: DequeState, ops, params, *, backend: str = "ref"
 ):
     """Sharded deque combine: one grid dispatch, program instance = shard."""
-    n = ops.shape[1]
-    windows_l, windows_r, sizes = jax.vmap(_deque_windows, in_axes=(0, None))(state, n)
-
-    if backend == "pallas":
-        resp, kinds, segs_l, segs_r, counts = dfc_deque_reduce_grid_call(
-            ops, params, windows_l, windows_r, sizes, interpret=True
-        )
-    elif backend == "pallas_tpu":
-        resp, kinds, segs_l, segs_r, counts = dfc_deque_reduce_grid_call(
-            ops, params, windows_l, windows_r, sizes, interpret=False
-        )
-    else:
-        resp, kinds, segs_l, segs_r, counts = jax.vmap(dfc_deque_reduce_ref)(
-            ops, params, windows_l, windows_r, sizes
-        )
-
+    windows_l, windows_r, sizes = jax.vmap(_deque_windows, in_axes=(0, None))(
+        state, ops.shape[1]
+    )
+    resp, kinds, segs_l, segs_r, counts = _kernel_or_ref(
+        backend, dfc_deque_reduce_grid_call, dfc_deque_reduce_ref,
+        ops, params, windows_l, windows_r, sizes,
+    )
     return jax.vmap(_deque_splice)(state, segs_l, segs_r, counts), resp, kinds
+
+
+def _one_shard(sharded_step, doc):
+    """Single-object twin of a sharded step: the same step over a one-shard
+    stack, so both entry points share one kernel path."""
+
+    @functools.partial(jax.jit, static_argnames=("backend",))
+    def step(state, ops, params, *, backend: str = "ref"):
+        one = jax.tree_util.tree_map(lambda leaf: leaf[None], state)
+        new, resp, kinds = sharded_step(one, ops[None], params[None], backend=backend)
+        return jax.tree_util.tree_map(lambda leaf: leaf[0], new), resp[0], kinds[0]
+
+    step.__doc__ = doc
+    return step
+
+
+dfc_combine_step = _one_shard(
+    dfc_sharded_combine_step,
+    "Stack combine phase: top window -> kernel -> splice above the top.",
+)
+dfc_queue_combine_step = _one_shard(
+    dfc_sharded_queue_combine_step,
+    "Queue combine phase: front window -> kernel -> masked ring splice.",
+)
+dfc_deque_combine_step = _one_shard(
+    dfc_sharded_deque_combine_step,
+    "Deque combine phase: end windows -> two-sided kernel -> ring splices.",
+)
 
 
 # --------------------------------------------------------------------- map
@@ -292,7 +239,7 @@ def dfc_sharded_deque_combine_step(
 def dfc_sharded_map_combine_step(state: MapState, keys, ops, params, *, backend: str = "ref"):
     """Sharded map combine: one grid dispatch, program instance = shard.
 
-    Unlike the ring kinds there is no window/splice factoring — the whole
+    Unlike the ring kinds there is no window/splice factoring — each shard's
     bucketed table rides through the kernel (map writes scatter by bucket,
     not contiguously at an end), and only the double-buffered ``count`` is
     published on the inactive slot here.
@@ -300,19 +247,11 @@ def dfc_sharded_map_combine_step(state: MapState, keys, ops, params, *, backend:
     s = ops.shape[0]
     rows = jnp.arange(s)
     active_counts = state.count[rows, (state.epoch // 2) % 2]
-
-    if backend in ("pallas", "pallas_tpu"):
-        mk, mv, mo, cnt, resp, kinds = dfc_map_reduce_grid_call(
-            state.keys, state.values, state.occupied, active_counts,
-            keys, ops, params, interpret=backend == "pallas",
-        )
-        cnt = cnt[:, 0]
-    else:
-        mk, mv, mo, cnt, resp, kinds = jax.vmap(dfc_map_reduce_ref)(
-            state.keys, state.values, state.occupied, active_counts,
-            keys, ops, params,
-        )
-
+    mk, mv, mo, cnt, resp, kinds = _kernel_or_ref(
+        backend, dfc_map_reduce_grid_call, dfc_map_reduce_ref,
+        state.keys, state.values, state.occupied, active_counts,
+        keys, ops, params,
+    )
     inactive = (state.epoch // 2 + 1) % 2
     new_state = MapState(
         keys=mk,
@@ -375,7 +314,7 @@ def dfc_lane_combine_step(state, ops, params, *, kind, lane, backend="jnp"):
     head-lane traffic moves only the head/left counter, tail-lane traffic
     only the values region and the tail/right counter, so the durable
     commit behind each dispatch persists just its own side.  Works for the
-    vmap (``jnp``) and Pallas-grid (``ref`` / ``pallas`` / ``pallas_tpu``)
+    vmap (``jnp``) and kernel-shaped (``ref`` / ``pallas``)
     paths via the shared ``_one_sharded_combine`` dispatch.
     """
     masked = _lane_mask_ops(kind, ops, lane)
@@ -478,165 +417,35 @@ def dfc_hetero_multi_combine_step(
     return out
 
 
-# ------------------------------------------------------------ K-phase fusion
-def _phase_grid_combine(kind: str, backend: str, state, ops, params, keys=None):
-    """Pallas-grid-over-the-phase-axis twin of the scanned K-phase chain.
-
-    One ``pallas_call`` with ``grid=(K,)``: program instance k runs phase k
-    over ALL shards of the kind group, with the working shard-stacked state
-    carried ACROSS grid steps in VMEM scratch (copied in from the input
-    state at k == 0) — the phase chain never round-trips through HBM between
-    phases.  Each instance applies the vectorized combine math (the same
-    ``STRUCTS[kind].combine`` the jnp backend vmaps), honors the
-    pass-through-batch contract (an all-``OP_NONE`` phase leaves state and
-    epoch untouched), and writes phase k's post-state, responses, and kinds
-    into the k-th row of the outputs.
-
-    ``backend`` picks interpret mode (``pallas``) or compiled TPU lowering
-    (``pallas_tpu``); the jnp/ref backends have no grid to run on — use the
-    scan variant.
-    """
-    from repro.core.jax_dfc import STRUCTS
-
-    if backend not in ("pallas", "pallas_tpu"):
-        raise ValueError(
-            f"phase_axis='grid' needs a Pallas backend, got {backend!r}"
-        )
-    k_phases, n_shards, n = ops.shape
-    if keys is None:
-        keys = jnp.zeros_like(ops)
-    leaves, treedef = jax.tree_util.tree_flatten(state)
-    n_leaves = len(leaves)
-    keyed = STRUCTS[kind].keyed
-    combine = jax.vmap(STRUCTS[kind].combine)
-
-    def kernel(*refs):
-        state_in = refs[:n_leaves]
-        keys_ref, ops_ref, par_ref = (
-            refs[n_leaves], refs[n_leaves + 1], refs[n_leaves + 2]
-        )
-        state_out = refs[n_leaves + 3: 2 * n_leaves + 3]
-        resp_ref, kind_ref = refs[2 * n_leaves + 3], refs[2 * n_leaves + 4]
-        scratch = refs[2 * n_leaves + 5:]
-        k = pl.program_id(0)
-
-        @pl.when(k == 0)
-        def _():
-            for dst, src in zip(scratch, state_in):
-                dst[...] = src[...]
-
-        carry = jax.tree_util.tree_unflatten(
-            treedef, [s[...] for s in scratch]
-        )
-        b_ops, b_params = ops_ref[0], par_ref[0]
-        if keyed:
-            combined, resp, kinds = combine(carry, keys_ref[0], b_ops, b_params)
-        else:
-            combined, resp, kinds = combine(carry, b_ops, b_params)
-        touched = jnp.any(b_ops != OP_NONE, axis=1)  # bool[S]
-
-        def _select(new_leaf, old_leaf):
-            t = touched.reshape(touched.shape + (1,) * (new_leaf.ndim - 1))
-            return jnp.where(t, new_leaf, old_leaf)
-
-        new_state = jax.tree_util.tree_map(_select, combined, carry)
-        for dst, out, leaf in zip(
-            scratch, state_out, jax.tree_util.tree_leaves(new_state)
-        ):
-            dst[...] = leaf
-            out[0] = leaf
-        resp_ref[0] = resp
-        kind_ref[0] = kinds
-
-    def _whole(leaf):  # one un-tiled block, revisited every grid step
-        nd = leaf.ndim
-        return pl.BlockSpec(leaf.shape, lambda k, _nd=nd: (0,) * _nd)
-
-    def _phase_row(shape):  # (1, ...) block at phase k
-        nd = len(shape)
-        return pl.BlockSpec(
-            (1,) + shape, lambda k, _nd=nd: (k,) + (0,) * _nd
-        )
-
-    # out_shape/out_specs MUST be flat tuples: a nested tuple makes
-    # pallas_call mis-pair specs with shapes and the kernel sees fewer out
-    # refs than leaves (observed: a stray scalar ref where the first state
-    # leaf should be).  Flatten here, regroup after the call.
-    outs = pl.pallas_call(
-        kernel,
-        grid=(k_phases,),
-        out_shape=tuple(
-            jax.ShapeDtypeStruct((k_phases,) + l.shape, l.dtype)
-            for l in leaves
-        )
-        + (
-            jax.ShapeDtypeStruct((k_phases, n_shards, n), jnp.float32),
-            jax.ShapeDtypeStruct((k_phases, n_shards, n), jnp.int32),
-        ),
-        in_specs=[_whole(l) for l in leaves]
-        + [
-            _phase_row((n_shards, n)),
-            _phase_row((n_shards, n)),
-            _phase_row((n_shards, n)),
-        ],
-        out_specs=tuple(_phase_row(l.shape) for l in leaves)
-        + (_phase_row((n_shards, n)), _phase_row((n_shards, n))),
-        scratch_shapes=[pltpu.VMEM(l.shape, l.dtype) for l in leaves],
-        interpret=backend == "pallas",
-    )(*leaves, keys, ops, params)
-    states = jax.tree_util.tree_unflatten(treedef, list(outs[:n_leaves]))
-    resp, kinds = outs[n_leaves], outs[n_leaves + 1]
-    return states, resp, kinds
-
-
-@functools.partial(
-    jax.jit, static_argnames=("kind", "backend", "unroll", "phase_axis")
-)
+@functools.partial(jax.jit, static_argnames=("kind", "backend", "unroll"))
 def dfc_multi_phase_step(
-    state, ops, params, *, kind, backend="ref", unroll=1, phase_axis="scan",
-    keys=None,
+    state, ops, params, *, kind, backend="ref", unroll=1, keys=None,
 ):
     """Fuse K combining PHASES of one kind group into a single dispatch and
     accumulate each phase's persist INTENTS device-side.
 
     ``ops`` / ``params`` are ``[K, S, N]`` per-phase announcement matrices.
-    The K phases chain exactly like K separate sharded combine calls — built
-    on the same ``_one_sharded_combine`` dispatch and honoring the
-    pass-through-batch contract (an all-``OP_NONE`` phase is a pure no-op:
-    state, epochs, counters untouched) — but nothing leaves the device
-    between phases, and nothing durable happens here at all.  Instead the
-    per-phase epoch/persist intents come back as one
+    The K phases chain exactly like K separate sharded combine calls — a
+    ``lax.scan`` over the phase axis, ``unroll`` phases per step, whose body
+    is the same ``_one_sharded_combine`` dispatch (so the Pallas backend
+    runs one shard-grid kernel per phase inside the fused program) and
+    honors the pass-through-batch contract (an all-``OP_NONE`` phase is a
+    pure no-op: state, epochs, counters untouched) — but nothing leaves the
+    device between phases, and nothing durable happens here at all.  Instead
+    the per-phase epoch/persist intents come back as one
     :class:`~repro.core.jax_dfc.PhaseIntents` log that the host drains
     asynchronously behind the device, issuing each phase's pwb/pfence batch
     in serial commit order (see ``ShardedDFCRuntime.phase_loop``).
-
-    ``phase_axis`` picks the fusion mechanism (both produce identical
-    results):
-
-      * ``"scan"`` — ``lax.scan`` over the phase axis, ``unroll`` phases per
-        step; works on every backend (the scan body dispatches
-        ``_one_sharded_combine``, so kernel backends still run one Pallas
-        grid per phase inside the fused program),
-      * ``"grid"`` — ONE Pallas grid over the phase axis itself
-        (``grid=(K,)``, program instance = phase, shard-stacked state
-        carried in VMEM scratch across grid steps); Pallas backends only.
 
     Returns ``(states, resp, kinds, intents)``: ``states`` with a leading K
     axis (``states[-1]`` is the final state), ``resp`` / ``kinds``
     ``[K, S, N]``, and ``intents`` the ``PhaseIntents`` record (cumulative
     counters start at zero — the caller adds its durable baseline).
     """
-    if phase_axis == "grid":
-        states, resp, kinds = _phase_grid_combine(
-            kind, backend, state, ops, params, keys=keys
-        )
-    elif phase_axis == "scan":
-        states, resp, kinds = dfc_sharded_multi_combine_step(
-            state, ops, params, kind=kind, backend=backend, unroll=unroll,
-            keys=keys,
-        )
-    else:
-        raise ValueError(f"unknown phase_axis {phase_axis!r}")
+    states, resp, kinds = dfc_sharded_multi_combine_step(
+        state, ops, params, kind=kind, backend=backend, unroll=unroll,
+        keys=keys,
+    )
     touched = jnp.any(ops != OP_NONE, axis=2)  # bool[K, S]
     per_phase_ops = jnp.sum((ops != OP_NONE).astype(jnp.int32), axis=2)
     intents = PhaseIntents(
@@ -650,7 +459,7 @@ def dfc_multi_phase_step(
 
 def dfc_hetero_multi_phase_step(
     groups, group_ops, group_params, *, backend="ref", unroll=1,
-    phase_axis="scan", group_keys=None,
+    group_keys=None,
 ):
     """Heterogeneous K-phase fusion: ``dfc_multi_phase_step`` per kind group
     present (``group_ops[kind]`` is ``[K, S_kind, N]``).  ``group_keys``
@@ -662,7 +471,7 @@ def dfc_hetero_multi_phase_step(
     for kind in sorted(groups):
         out[kind] = dfc_multi_phase_step(
             groups[kind], group_ops[kind], group_params[kind],
-            kind=kind, backend=backend, unroll=unroll, phase_axis=phase_axis,
+            kind=kind, backend=backend, unroll=unroll,
             keys=None if group_keys is None else group_keys.get(kind),
         )
     return out

@@ -202,8 +202,8 @@ def dfc_deque_reduce_ref(ops, params, window_l, window_r, size):
 
 
 def dfc_map_reduce_ref(mkeys, mvals, mocc, count, lkeys, ops, params):
-    """Oracle for ``_map_reduce_math``: same lane-order scan, but probing via
-    full-table masks instead of the kernel's dynamic_slice bucket windows."""
+    """Oracle for the map kernel: same lane-order walk, but probing via
+    full-table masks instead of the kernel's one-row bucket windows."""
     cap = mkeys.shape[0]
     bslots = min(cap, MAP_BUCKET_SLOTS)
     n_buckets = cap // bslots

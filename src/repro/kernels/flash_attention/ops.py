@@ -18,10 +18,11 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 
 def attention(q, k, v, *, causal=True, backend: str = "ref", **kw):
-    if backend == "pallas":
-        return flash_attention(q, k, v, causal=causal, interpret=True, **kw)
-    if backend == "pallas_tpu":
-        return flash_attention(q, k, v, causal=causal, interpret=False, **kw)
+    if backend == "pallas":  # interpreted on the CPU only
+        return flash_attention(
+            q, k, v, causal=causal,
+            interpret=jax.default_backend() == "cpu", **kw,
+        )
     if backend == "chunked":
         return chunked_attention(q, k, v, causal=causal, **kw)
     return attention_ref(q, k, v, causal=causal)

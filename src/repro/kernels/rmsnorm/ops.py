@@ -1,5 +1,7 @@
 """Public wrapper for fused RMSNorm."""
 
+import jax
+
 from repro.kernels.rmsnorm.kernel import rmsnorm
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
 
@@ -7,10 +9,8 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref
 def rmsnorm_op(x, w, *, backend: str = "ref", eps: float = 1e-6):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
-    if backend == "pallas":
-        out = rmsnorm(x2, w, eps=eps, interpret=True)
-    elif backend == "pallas_tpu":
-        out = rmsnorm(x2, w, eps=eps, interpret=False)
+    if backend == "pallas":  # interpreted on the CPU only
+        out = rmsnorm(x2, w, eps=eps, interpret=jax.default_backend() == "cpu")
     else:
         out = rmsnorm_ref(x2, w, eps)
     return out.reshape(shape)

@@ -58,6 +58,7 @@ import numpy as np
 
 from repro.checkpoint.dfc_checkpoint import CrashNow, FaultInjector, SimFS
 from repro.configs import ARCH_IDS, get_config, get_reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.tuned import apply_tuning
 from repro.core.jax_dfc import (
     CAS_DOM,
@@ -1526,7 +1527,8 @@ def _serve_continuous(
         print("exactly-once: OK (sessions + token indices)")
 
 
-def main():
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true")
@@ -1596,7 +1598,7 @@ def main():
                          "sidecar under the tier root (with --state-dir), "
                          "metrics + Chrome trace exports, and p50/p99 "
                          "admission latency in the tier report")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = apply_tuning(get_reduced(args.arch) if args.reduced else get_config(args.arch))
     if not args.tier_only and (cfg.embedding_inputs or cfg.family == "vlm"):
@@ -1641,7 +1643,9 @@ def main():
     k = args.k_classes if args.k_classes >= 2 else 0
     tier_kw = dict(
         n_queues=args.queues,
-        capacity=4096,
+        # the session map holds up to two entries per session in buckets of
+        # 8: keep it at most 1/8 full so no arrival meets a full bucket
+        capacity=max(4096, 16 * (1 << max(0, n_sessions - 1).bit_length())),
         lanes=max(arrival, args.batch) * 2,
         reshard_backlog=args.reshard_backlog or None,
         pipeline=args.pipeline,
@@ -1846,10 +1850,8 @@ def main():
                 f"{n_e} chrome events under {obs.root / 'obs'})"
             )
     if args.expect_exactly_once:
-        served = _read_served(state_dir)
-        expect = sorted(range(1, n_sessions + 1))
-        assert sorted(served) == expect and len(served) == len(set(served)), (
-            f"exactly-once violated: served={sorted(served)} expected={expect}"
+        verify_exactly_once(
+            range(1, n_sessions + 1), 0, _read_served(state_dir), {}
         )
         print(f"exactly-once OK: {n_sessions} sessions, none lost, none duplicated")
 

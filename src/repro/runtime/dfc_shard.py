@@ -442,6 +442,32 @@ def _group_ids(kinds: Tuple[str, ...]) -> Dict[str, Tuple[int, ...]]:
     return {k: tuple(v) for k, v in out.items()}
 
 
+def _split_groups(x, gids, axis: int):
+    """``{kind: x's rows of that kind}`` along the shard ``axis``.
+
+    Kind groups are static, so this is a static slice per contiguous group
+    (a constant-index take otherwise): scatters and gathers over the shard
+    axis of a kind group crash the TPU compiler's fused-scatter emitter
+    for some group shapes (4 queue shards x 128 lanes, jax 0.9.0)."""
+    out = {}
+    for k, ids in gids.items():
+        if ids == tuple(range(ids[0], ids[-1] + 1)):
+            out[k] = jax.lax.slice_in_dim(x, ids[0], ids[-1] + 1, axis=axis)
+        else:
+            out[k] = jnp.take(x, np.asarray(ids), axis=axis)
+    return out
+
+
+def _merge_groups(parts, gids, axis: int):
+    """Inverse of :func:`_split_groups`: per-kind blocks back in global
+    shard order along ``axis``."""
+    order = [s for k in sorted(gids) for s in gids[k]]
+    merged = jnp.concatenate([parts[k] for k in sorted(gids)], axis=axis)
+    if order == list(range(len(order))):
+        return merged
+    return jnp.take(merged, np.argsort(order), axis=axis)
+
+
 @functools.partial(jax.jit, static_argnames=("kinds", "lanes", "backend"))
 def hetero_step(
     groups, table, keys, ops, params, meta, *, kinds: Tuple[str, ...],
@@ -465,20 +491,15 @@ def hetero_step(
     )
 
     gids = _group_ids(kinds)
-    group_ops = {k: shard_ops[jnp.asarray(ids)] for k, ids in gids.items()}
-    group_params = {k: shard_params[jnp.asarray(ids)] for k, ids in gids.items()}
-    group_keys = {k: shard_keys[jnp.asarray(ids)] for k, ids in gids.items()}
+    group_ops = _split_groups(shard_ops, gids, 0)
     combined = dfc_hetero_combine_step(
-        groups, group_ops, group_params, backend=backend, group_keys=group_keys
+        groups, group_ops, _split_groups(shard_params, gids, 0),
+        backend=backend, group_keys=_split_groups(shard_keys, gids, 0),
     )
 
-    resp_mat = jnp.zeros((n_shards, lanes), jnp.float32)
-    kind_mat = jnp.full((n_shards, lanes), R_NONE, jnp.int32)
     new_groups = {}
-    for k in sorted(gids):
-        ids = gids[k]
-        rows = jnp.asarray(ids)
-        new_state, s_resp, s_kinds = combined[k]
+    for k, ids in gids.items():
+        new_state = combined[k][0]
         g_touched = jnp.any(group_ops[k] != OP_NONE, axis=1)
 
         def _select(new_leaf, old_leaf, t=g_touched, m=len(ids)):
@@ -486,8 +507,8 @@ def hetero_step(
             return jnp.where(tt, new_leaf, old_leaf)
 
         new_groups[k] = jax.tree_util.tree_map(_select, new_state, groups[k])
-        resp_mat = resp_mat.at[rows].set(s_resp)
-        kind_mat = kind_mat.at[rows].set(s_kinds)
+    resp_mat = _merge_groups({k: c[1] for k, c in combined.items()}, gids, 0)
+    kind_mat = _merge_groups({k: c[2] for k, c in combined.items()}, gids, 0)
 
     touched = jnp.any(shard_ops != OP_NONE, axis=1)
     new_meta = dict(meta)
@@ -545,32 +566,23 @@ def hetero_multi_step(
     shard_keys = jnp.stack([r[6] for r in routed])
 
     gids = _group_ids(kinds)
-    group_ops = {k: shard_ops[:, jnp.asarray(ids)] for k, ids in gids.items()}
-    group_params = {
-        k: shard_params[:, jnp.asarray(ids)] for k, ids in gids.items()
-    }
-    group_keys = {
-        k: shard_keys[:, jnp.asarray(ids)] for k, ids in gids.items()
-    }
     multi = dfc_hetero_multi_combine_step(
-        groups, group_ops, group_params, backend=backend, unroll=unroll,
-        group_keys=group_keys,
+        groups, _split_groups(shard_ops, gids, 1),
+        _split_groups(shard_params, gids, 1), backend=backend, unroll=unroll,
+        group_keys=_split_groups(shard_keys, gids, 1),
     )
 
-    resp_mat = jnp.zeros((n_batches, n_shards, lanes), jnp.float32)
-    kind_mat = jnp.full((n_batches, n_shards, lanes), R_NONE, jnp.int32)
-    epochs = jnp.zeros((n_batches, n_shards), jnp.int32)
-    epochs_before = jnp.zeros((n_shards,), jnp.int32)
-    new_groups, states = {}, {}
-    for k in sorted(gids):
-        rows = jnp.asarray(gids[k])
-        st, s_resp, s_kinds = multi[k]
-        states[k] = st
-        new_groups[k] = jax.tree_util.tree_map(lambda leaf: leaf[-1], st)
-        resp_mat = resp_mat.at[:, rows].set(s_resp)
-        kind_mat = kind_mat.at[:, rows].set(s_kinds)
-        epochs = epochs.at[:, rows].set(st.epoch)
-        epochs_before = epochs_before.at[rows].set(groups[k].epoch)
+    states = {k: m[0] for k, m in multi.items()}
+    new_groups = {
+        k: jax.tree_util.tree_map(lambda leaf: leaf[-1], st)
+        for k, st in states.items()
+    }
+    resp_mat = _merge_groups({k: m[1] for k, m in multi.items()}, gids, 1)
+    kind_mat = _merge_groups({k: m[2] for k, m in multi.items()}, gids, 1)
+    epochs = _merge_groups({k: st.epoch for k, st in states.items()}, gids, 1)
+    epochs_before = _merge_groups(
+        {k: groups[k].epoch for k in gids}, gids, 0
+    )
 
     touched = jnp.any(shard_ops != OP_NONE, axis=2)  # [B, S]
     per_batch_ops = jnp.sum((shard_ops != OP_NONE).astype(jnp.int32), axis=2)
@@ -600,7 +612,6 @@ def hetero_multi_step(
 def _hetero_phase_loop_impl(
     groups, table, keys, ops, params, meta, *, kinds: Tuple[str, ...],
     lanes: int, backend: str = "jnp", unroll: int = 1,
-    phase_axis: str = "scan",
 ):
     """Trace body of :func:`hetero_phase_loop_step` (jitted twice below —
     once with the kind-group buffers donated, once without)."""
@@ -619,46 +630,37 @@ def _hetero_phase_loop_impl(
     )  # [K, S, L], [K, S, L], [K, B], [K, B], ...
 
     gids = _group_ids(kinds)
-    group_ops = {k: shard_ops[:, jnp.asarray(ids)] for k, ids in gids.items()}
-    group_params = {
-        k: shard_params[:, jnp.asarray(ids)] for k, ids in gids.items()
-    }
-    group_keys = {
-        k: shard_keys[:, jnp.asarray(ids)] for k, ids in gids.items()
-    }
     multi = dfc_hetero_multi_phase_step(
-        groups, group_ops, group_params,
-        backend=backend, unroll=unroll, phase_axis=phase_axis,
-        group_keys=group_keys,
+        groups, _split_groups(shard_ops, gids, 1),
+        _split_groups(shard_params, gids, 1),
+        backend=backend, unroll=unroll,
+        group_keys=_split_groups(shard_keys, gids, 1),
     )
 
     k_phases = ops.shape[0]
-    resp_mat = jnp.zeros((k_phases, n_shards, lanes), jnp.float32)
-    kind_mat = jnp.full((k_phases, n_shards, lanes), R_NONE, jnp.int32)
-    epochs = jnp.zeros((k_phases, n_shards), jnp.int32)
-    epochs_before = jnp.zeros((n_shards,), jnp.int32)
-    touched_all = jnp.zeros((k_phases, n_shards), bool)
-    phases_cum = jnp.zeros((k_phases, n_shards), jnp.int32)
-    ops_cum = jnp.zeros((k_phases, n_shards), jnp.int32)
-    new_groups, states = {}, {}
-    for k in sorted(gids):
-        rows = jnp.asarray(gids[k])
-        st, s_resp, s_kinds, intents = multi[k]
-        states[k] = st
-        new_groups[k] = jax.tree_util.tree_map(lambda leaf: leaf[-1], st)
-        resp_mat = resp_mat.at[:, rows].set(s_resp)
-        kind_mat = kind_mat.at[:, rows].set(s_kinds)
-        epochs = epochs.at[:, rows].set(intents.epoch)
-        epochs_before = epochs_before.at[rows].set(groups[k].epoch)
-        touched_all = touched_all.at[:, rows].set(intents.touched)
-        # re-base the dispatch-relative cumulative counters on the fabric's
-        # durable meta: row k is then exactly what phase k's slot persists
-        phases_cum = phases_cum.at[:, rows].set(
-            meta["phases"][rows][None] + intents.phases_cum
-        )
-        ops_cum = ops_cum.at[:, rows].set(
-            meta["ops_combined"][rows][None] + intents.ops_cum
-        )
+    states = {k: m[0] for k, m in multi.items()}
+    new_groups = {
+        k: jax.tree_util.tree_map(lambda leaf: leaf[-1], st)
+        for k, st in states.items()
+    }
+    resp_mat = _merge_groups({k: m[1] for k, m in multi.items()}, gids, 1)
+    kind_mat = _merge_groups({k: m[2] for k, m in multi.items()}, gids, 1)
+    intents = {k: m[3] for k, m in multi.items()}
+    epochs = _merge_groups({k: i.epoch for k, i in intents.items()}, gids, 1)
+    epochs_before = _merge_groups(
+        {k: groups[k].epoch for k in gids}, gids, 0
+    )
+    touched_all = _merge_groups(
+        {k: i.touched for k, i in intents.items()}, gids, 1
+    )
+    # re-base the dispatch-relative cumulative counters on the fabric's
+    # durable meta: row k is then exactly what phase k's slot persists
+    phases_cum = meta["phases"][None] + _merge_groups(
+        {k: i.phases_cum for k, i in intents.items()}, gids, 1
+    )
+    ops_cum = meta["ops_combined"][None] + _merge_groups(
+        {k: i.ops_cum for k, i in intents.items()}, gids, 1
+    )
 
     new_meta = dict(meta)
     new_meta["phases"] = phases_cum[-1]
@@ -680,7 +682,7 @@ def _hetero_phase_loop_impl(
     )
 
 
-_PHASE_LOOP_STATICS = ("kinds", "lanes", "backend", "unroll", "phase_axis")
+_PHASE_LOOP_STATICS = ("kinds", "lanes", "backend", "unroll")
 _phase_loop_step_plain = jax.jit(
     _hetero_phase_loop_impl, static_argnames=_PHASE_LOOP_STATICS
 )
@@ -696,7 +698,7 @@ _phase_loop_step_donated = jax.jit(
 def hetero_phase_loop_step(
     groups, table, keys, ops, params, meta, *, kinds: Tuple[str, ...],
     lanes: int, backend: str = "jnp", unroll: int = 1,
-    phase_axis: str = "scan", donate: Optional[bool] = None,
+    donate: Optional[bool] = None,
 ):
     """Route + combine K PHASES over a heterogeneous fabric in ONE dispatch,
     accumulating each phase's persist intents device-side.
@@ -709,9 +711,7 @@ def hetero_phase_loop_step(
     ``hetero_step`` calls would, but the whole schedule costs one dispatch
     and the stacked shard state never leaves the device between phases
     (``donate=True`` — the default off-CPU — additionally donates the old
-    group buffers to the dispatch).  ``phase_axis`` picks ``lax.scan``
-    (every backend) or the Pallas grid over the phase axis (Pallas
-    backends); see ``dfc_multi_phase_step``.
+    group buffers to the dispatch); see ``dfc_multi_phase_step``.
 
     Returns ``(new_groups, new_meta, responses [K, L], out_kinds [K, L],
     states, epochs_before i32[S], intents)`` where ``states[kind]`` carries
@@ -725,8 +725,7 @@ def hetero_phase_loop_step(
     fn = _phase_loop_step_donated if donate else _phase_loop_step_plain
     return fn(
         groups, table, keys, ops, params, meta,
-        kinds=kinds, lanes=lanes, backend=backend,
-        unroll=unroll, phase_axis=phase_axis,
+        kinds=kinds, lanes=lanes, backend=backend, unroll=unroll,
     )
 
 
@@ -1768,7 +1767,6 @@ class ShardedDFCRuntime:
         schedule: Sequence[Tuple[int, int, Any, Any, Any]],
         *,
         unroll: Optional[int] = None,
-        phase_axis: str = "scan",
     ) -> List[Dict[str, Any]]:
         """Fuse K combining phases into ONE device dispatch, then drain the
         per-phase persist intents host-side — subsuming ``combine_phase`` +
@@ -1780,8 +1778,8 @@ class ShardedDFCRuntime:
         be monotone across the schedule, the ``announce`` contract).  The
         device side routes, combines, and accumulates every phase's
         epoch/persist intents in device arrays (``hetero_phase_loop_step``:
-        one ``lax.scan`` — or one Pallas grid over the phase axis — per kind
-        group, group buffers donated off-CPU so stacked shard state never
+        one ``lax.scan`` over the phases per kind group, group buffers
+        donated off-CPU so stacked shard state never
         leaves the device between phases), with the whole schedule staged
         through the announcement ring in one scatter when it fits.  The host
         then drains the intent log in strict serial order — for each phase:
@@ -1870,7 +1868,6 @@ class ShardedDFCRuntime:
             lanes=self.lanes,
             backend=self.backend,
             unroll=self.depth if unroll is None else int(unroll),
-            phase_axis=phase_axis,
         )
         self.last_dispatch = [((t, tok),) for t, tok, *_ in batches]
         if self.obs.enabled:
@@ -1879,7 +1876,6 @@ class ShardedDFCRuntime:
                 fused=True,
                 k_phases=k_phases,
                 pad=pad,
-                phase_axis=phase_axis,
                 batches=[[t, tok] for t, tok, *_ in batches],
             )
 
@@ -2693,9 +2689,9 @@ class ShardedDFCRuntime:
                 for i in range(occ.shape[0])
                 if occ[i]
             ]
-        cap = one.values.shape[0]
-        e = one.active_ends()
-        return [float(one.values[i % cap]) for i in range(int(e[0]), int(e[1]))]
+        values = np.asarray(one.values)  # one fetch, not one per element
+        lo, hi = (int(x) for x in np.asarray(one.active_ends()))
+        return [float(v) for v in values[np.arange(lo, hi) % values.shape[0]]]
 
     def shard_sizes(self) -> np.ndarray:
         """Committed sizes of every shard (for hot/cold reshard policies) —

@@ -2,8 +2,9 @@
 
 Covers the ISSUE-6 tentpole acceptance criteria: a whole SCHEDULE of
 combining phases runs as ONE device dispatch (``lax.scan`` over the phase
-axis, with a Pallas grid-over-phases twin), accumulating per-phase persist
-INTENTS in device arrays; the host then drains the intent log and issues
+axis, on the vmapped combine or the Pallas shard-grid kernels),
+accumulating per-phase persist INTENTS in device arrays; the host then
+drains the intent log and issues
 the pwb/pfence batches behind the device.  The durable schedule the drain
 replays is op-for-op the serial one, so:
 
@@ -15,9 +16,11 @@ replays is op-for-op the serial one, so:
   recover with per-thread detectability verdicts intact and replay to
   exactly-once (the device is up to K phases ahead of the host at every
   one of these points: the dispatch completed before the drain started);
-- the scan and Pallas-grid phase axes must be bit-identical.
+- the vmapped-jnp and Pallas-grid backends must be bit-identical;
+- the donated phase-loop program (the one the chip runs) must behave
+  exactly like the undonated one.
 
-Fast representatives run in tier-1; the full kind x phase_axis sweep grid
+Fast representatives run in tier-1; the full kind x backend sweep grid
 is ``slow``.
 """
 
@@ -150,25 +153,74 @@ def test_phase_loop_records_match_read_responses(tmp_path):
 
 
 def test_phase_loop_scan_grid_parity(tmp_path):
-    """The ``lax.scan`` phase axis and the Pallas grid-over-phases axis
-    produce identical records, durable stats, and contents."""
+    """The phase scan over the vmapped jnp combine and over the Pallas
+    shard-grid kernels produce identical records, durable stats, and
+    contents."""
     kinds = ["queue", "stack", "deque"]
     sched = _schedule(kinds, 3, 2, 5, seed=3, mixed=True)
     runs = {}
-    for axis, backend in (("scan", "ref"), ("grid", "pallas")):
-        fs = SimFS(tmp_path / axis)
+    for backend in ("jnp", "pallas"):
+        fs = SimFS(tmp_path / backend)
         rt = ShardedDFCRuntime(
             kinds, 3, CAP, LANES, fs=fs, n_threads=2, backend=backend,
         )
-        recs = rt.phase_loop(sched, phase_axis=axis)
-        runs[axis] = (recs, dict(fs.stats), _fabric_contents(rt))
-    recs_s, stats_s, cont_s = runs["scan"]
-    recs_g, stats_g, cont_g = runs["grid"]
+        recs = rt.phase_loop(sched)
+        runs[backend] = (recs, dict(fs.stats), _fabric_contents(rt))
+    recs_s, stats_s, cont_s = runs["jnp"]
+    recs_g, stats_g, cont_g = runs["pallas"]
     assert stats_s == stats_g
     assert cont_s == cont_g
     for a, b in zip(recs_s, recs_g):
         assert a["resp"] == b["resp"] and a["kinds"] == b["kinds"]
         assert a["targets"] == b["targets"]
+
+
+@pytest.fixture
+def donated(monkeypatch):
+    """Run ``phase_loop`` through the donated program, as it runs off the
+    CPU: the old kind-group buffers are consumed by the dispatch, so any
+    later read of them raises instead of passing silently."""
+    from repro.runtime import dfc_shard
+
+    monkeypatch.setattr(
+        dfc_shard, "_phase_loop_step_plain", dfc_shard._phase_loop_step_donated
+    )
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_phase_loop_donated_matches_serial_drive(tmp_path, donated, backend):
+    """The donated phase loop consumes the old group buffers and still
+    reproduces the serial drive's records, counts, and contents."""
+    kinds = ["queue", "stack", "deque", "map"]
+    sched = _schedule(kinds, 3, 2, 5, seed=13, mixed=True)
+    fs1 = SimFS(tmp_path / "serial")
+    rt1 = ShardedDFCRuntime(
+        kinds, 4, CAP, LANES, fs=fs1, n_threads=2, chain=2, backend=backend,
+    )
+    serial = _drive_serial(rt1, sched)
+
+    fs2 = SimFS(tmp_path / "fused")
+    rt2 = ShardedDFCRuntime(
+        kinds, 4, CAP, LANES, fs=fs2, n_threads=2, backend=backend,
+    )
+    before = jax.tree_util.tree_leaves(rt2.groups)
+    records = rt2.phase_loop(sched[:4])
+    assert all(leaf.is_deleted() for leaf in before), "groups not donated"
+    records += rt2.phase_loop(sched[4:])  # a second dispatch on the outputs
+
+    assert dict(fs1.stats) == dict(fs2.stats)
+    for rec, want in zip(records, serial):
+        assert rec["resp"] == want["resp"]
+        assert rec["kinds"] == want["kinds"]
+        assert rec["targets"] == want["targets"]
+    for s in range(4):
+        assert rt1.shard_contents(s) == rt2.shard_contents(s)
+
+
+def test_phase_loop_donated_crash_sweep(tmp_path, donated):
+    """Crash/recover/replay around the donated program: every third
+    persistence op of the drain, exactly-once after each."""
+    _sweep(tmp_path, ["queue", "stack"], seed=29, step=3)
 
 
 def test_phase_loop_empty_and_single_phase(tmp_path):
@@ -187,7 +239,7 @@ def test_phase_loop_empty_and_single_phase(tmp_path):
 
 # -------------------------------------------------------- crash sweeps
 def _crash_scenario(tmp, crash_at, kinds, sched, *, n_threads,
-                    phase_axis="scan", backend="ref"):
+                    backend="ref"):
     inj = FaultInjector(crash_at=crash_at)
     fs = SimFS(tmp, inj)
     n_shards = len(kinds)
@@ -196,7 +248,7 @@ def _crash_scenario(tmp, crash_at, kinds, sched, *, n_threads,
         backend=backend,
     )
     try:
-        rt.phase_loop(sched, phase_axis=phase_axis)
+        rt.phase_loop(sched)
     except CrashNow:
         pass
     rt2, report = ShardedDFCRuntime.recover(
@@ -206,8 +258,7 @@ def _crash_scenario(tmp, crash_at, kinds, sched, *, n_threads,
     return rt2, report, inj.count
 
 
-def _verify_exactly_once(rt2, report, sched, *, n_threads,
-                         phase_axis="scan"):
+def _verify_exactly_once(rt2, report, sched, *, n_threads):
     """Soundness: every op a verdict reports applied is durably in the
     fabric.  Completeness: replay the announced-not-applied ops, re-drive
     the never-announced phases through a fresh fused loop, and check every
@@ -229,27 +280,25 @@ def _verify_exactly_once(rt2, report, sched, *, n_threads,
     surfaced = {t: report[t]["token"] or 0 for t in range(n_threads)}
     remaining = [e for e in sched if e[1] > surfaced[e[0]]]
     if remaining:
-        rt2.phase_loop(remaining, phase_axis=phase_axis)
+        rt2.phase_loop(remaining)
     expect = sorted(p for (_t, _tok, _k, _o, ps) in sched for p in ps)
     assert _fabric_contents(rt2) == expect, "lost or duplicated ops"
 
 
 def _sweep(tmp_path, kinds, *, n_threads=2, n_rounds=3, per_thread=4,
-           step=1, seed=42, phase_axis="scan", backend="ref"):
+           step=1, seed=42, backend="ref"):
     sched = _schedule(kinds, n_rounds, n_threads, per_thread, seed=seed)
     _rt_dry, report_dry, total = _crash_scenario(
         tmp_path / "dry", None, kinds, sched, n_threads=n_threads,
-        phase_axis=phase_axis, backend=backend,
+        backend=backend,
     )
     assert total > 40  # the drain really is issuing the serial op count
     for k in range(1, total + 1, step):
         rt2, report, _ = _crash_scenario(
             tmp_path / f"k{k}", k, kinds, sched, n_threads=n_threads,
-            phase_axis=phase_axis, backend=backend,
+            backend=backend,
         )
-        _verify_exactly_once(
-            rt2, report, sched, n_threads=n_threads, phase_axis=phase_axis,
-        )
+        _verify_exactly_once(rt2, report, sched, n_threads=n_threads)
 
 
 def test_phase_loop_crash_sweep_queue(tmp_path):
@@ -306,17 +355,11 @@ def test_crash_between_phases_k_and_k_minus_1(tmp_path):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("kind", ["stack", "queue", "deque"])
-@pytest.mark.parametrize("phase_axis,backend", [
-    ("scan", "ref"), ("scan", "jnp"), ("grid", "pallas"),
-])
-def test_phase_loop_crash_sweep_grid(tmp_path, kind, phase_axis, backend):
+@pytest.mark.parametrize("backend", ["ref", "jnp", "pallas"])
+def test_phase_loop_crash_sweep_grid(tmp_path, kind, backend):
     """Full grid: crash at every persistence op for each structure kind on
-    both phase axes (scan on ref/jnp backends, Pallas grid in interpret
-    mode)."""
-    _sweep(
-        tmp_path, [kind, kind], seed=17, phase_axis=phase_axis,
-        backend=backend,
-    )
+    every backend (the Pallas shard grid in interpret mode)."""
+    _sweep(tmp_path, [kind, kind], seed=17, backend=backend)
 
 
 def test_request_tier_bulk_waves_match_serial_submits():
