@@ -40,8 +40,8 @@ import numpy as np
 
 import jax
 
-from repro.nvm.memory import PersistStats
-from repro.obs import NULL_OBS
+from repro.nvm.memory import DEFAULT_TAG, PersistStats
+from repro.obs import NULL_OBS, count, recording, span
 
 
 class CrashNow(Exception):
@@ -95,32 +95,41 @@ class SimFS:
         self.obs.on_pwb(rel, tag)
 
     def fsync(self, rels: Optional[List[str]] = None, tag: Optional[str] = None) -> None:
-        """pfence: flush pending writes to the real filesystem."""
-        self.stats["pfence"] += 1
-        self.pstats.count_pfence(tag)
-        self.injector.tick()
-        items = (
-            list(self.pending.items())
-            if rels is None
-            else [(r, self.pending[r]) for r in rels if r in self.pending]
-        )
-        for rel, data in items:
-            p = self._p(rel)
-            p.parent.mkdir(parents=True, exist_ok=True)
-            p.write_bytes(data)
-            self.pending.pop(rel, None)
+        """pfence: flush pending writes to the real filesystem (program span
+        ``pfence.<tag>``)."""
+        with span("pfence", tag or DEFAULT_TAG):
+            self.stats["pfence"] += 1
+            self.pstats.count_pfence(tag)
+            self.injector.tick()
+            items = (
+                list(self.pending.items())
+                if rels is None
+                else [(r, self.pending[r]) for r in rels if r in self.pending]
+            )
+            for rel, data in items:
+                p = self._p(rel)
+                p.parent.mkdir(parents=True, exist_ok=True)
+                p.write_bytes(data)
+                self.pending.pop(rel, None)
         self.obs.on_pfence(rels, tag)
 
     def read(self, rel: str) -> Optional[bytes]:
         """Reads see the buffered (volatile) view, like a CPU cache."""
         if rel in self.pending:
             return self.pending[rel]
-        p = self._p(rel)
-        return p.read_bytes() if p.exists() else None
+        return self.read_durable(rel)
 
     def read_durable(self, rel: str) -> Optional[bytes]:
+        """Read from the file system (counters ``disk_reads`` and
+        ``disk_read_bytes``)."""
         p = self._p(rel)
-        return p.read_bytes() if p.exists() else None
+        if not p.exists():
+            return None
+        data = p.read_bytes()
+        if recording():
+            count("disk_reads")
+            count("disk_read_bytes", len(data))
+        return data
 
     def exists(self, rel: str) -> bool:
         return rel in self.pending or self._p(rel).exists()

@@ -2,11 +2,13 @@
 
 Entry point is :class:`FabricObserver` (or the module-level
 :data:`NULL_OBS` default — a disabled observer whose every method is a
-no-op).  Construction is deliberately decoupled from the runtime: an
-observer is handed to ``ShardedDFCRuntime`` / ``RequestQueueTier`` /
-``SimFS`` by reference, never imported by them at module level, so the
-``obs`` package stays dependency-free and the runtime works identically
-without it.
+no-op).  Spans and counters on the profiler's clock (``span``, ``count``,
+``profile_totals``, in ``profile.py``) apply to both: a profiler session,
+not the observer, switches them on.  Construction is deliberately
+decoupled from the runtime: an observer is handed to ``ShardedDFCRuntime``
+/ ``RequestQueueTier`` / ``SimFS`` by reference, never imported by them at
+module level, so the ``obs`` package stays dependency-free (jax is imported
+on first use of a span) and the runtime works identically without it.
 
 The one invariant everything here is built around: **observability never
 adds a persistence instruction**.  Durable-state digests and pwb/pfence
@@ -16,6 +18,7 @@ sidecar's durability rides the fabric's own pfences (see ``trace.py``).
 
 from __future__ import annotations
 
+import time
 from pathlib import Path
 from typing import Any, Optional
 
@@ -26,6 +29,7 @@ from .metrics import (
     bridge_persist_stats,
     to_chrome_trace,
 )
+from .profile import count, profile_totals, recording, span
 from .trace import (
     EV_ANNOUNCE,
     EV_DISPATCH,
@@ -39,6 +43,7 @@ from .trace import (
     EV_RESHARD,
     EV_RETIRE,
     EV_SCHED,
+    EV_SPAN,
     EV_TOPOLOGY,
     EV_VERDICT,
     NULL_RECORDER,
@@ -62,6 +67,10 @@ __all__ = [
     "to_chrome_trace",
     "durable_digest",
     "read_trace",
+    "span",
+    "count",
+    "recording",
+    "profile_totals",
     "EV_ANNOUNCE",
     "EV_DISPATCH",
     "EV_DRAIN",
@@ -74,6 +83,7 @@ __all__ = [
     "EV_RESHARD",
     "EV_RETIRE",
     "EV_SCHED",
+    "EV_SPAN",
     "EV_TOPOLOGY",
     "EV_VERDICT",
 ]
@@ -92,8 +102,8 @@ class NullObserver:
     def event(self, ev: str, **fields: Any):
         return self.trace.event(ev, **fields)
 
-    def span(self, ev: str, **fields: Any):
-        return self.trace.span(ev, **fields)
+    # the profiler span: one is_enabled() check while no session records
+    span = staticmethod(span)
 
     def on_pwb(self, rel: str, tag: Optional[str]) -> None:
         return None
@@ -109,6 +119,27 @@ class NullObserver:
 
 
 NULL_OBS = NullObserver()
+
+
+class _RecordedSpan:
+    """``span(name)`` that also appends an ``EV_SPAN`` event at exit (its
+    ``ts_us`` marks the end, ``dur_us`` spans back)."""
+
+    __slots__ = ("trace", "name", "inner", "t0")
+
+    def __init__(self, trace, name: str):
+        self.trace, self.name = trace, name
+
+    def __enter__(self):
+        self.inner = span(self.name)
+        self.inner.__enter__()
+        self.t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        dur_us = (time.perf_counter_ns() - self.t0) / 1e3
+        self.inner.__exit__(*exc)
+        self.trace.event(EV_SPAN, name=self.name, dur_us=dur_us)
+        return False
 
 
 class FabricObserver(NullObserver):
@@ -128,13 +159,17 @@ class FabricObserver(NullObserver):
     def trace_path(self) -> Optional[Path]:
         return self.trace.path
 
+    def span(self, name: str, tag: Optional[str] = None):
+        """The profiler span, plus one flight-recorder event carrying its
+        wall duration on the recorder's own timeline (which, unlike the
+        profiler's trace, outlives a crash)."""
+        return _RecordedSpan(self.trace, name if tag is None else f"{name}.{tag}")
+
     def on_pwb(self, rel: str, tag: Optional[str]) -> None:
         self.trace.on_pwb(rel, tag)
-        self.metrics.counter("obs_pwb", tag=tag or "untagged")
 
     def on_pfence(self, rels, tag: Optional[str]) -> None:
         self.trace.on_pfence(rels, tag)
-        self.metrics.counter("obs_pfence", tag=tag or "untagged")
 
     def flush(self) -> None:
         self.trace.flush()

@@ -197,19 +197,21 @@ def to_chrome_trace(events: List[Dict[str, Any]], path) -> int:
     """Render recorded trace events as a Chrome trace-event JSON array
     (load in chrome://tracing or ui.perfetto.dev).  Events with ``dur_us``
     become complete ('X') slices re-based to their begin time; the rest
-    become instants ('i').  Returns the event count."""
+    become instants ('i').  A program span's slice carries the span's name.
+    Returns the event count."""
     out = []
     for e in events:
         ts = float(e.get("ts_us", 0.0))
         dur = e.get("dur_us")
+        ev = e.get("ev", "?")
         rec = {
-            "name": e.get("ev", "?"),
+            "name": e["name"] if ev == "span" else ev,
             "pid": 0,
             "tid": int(e.get("thread", 0)),
             "args": {
                 k: v
                 for k, v in e.items()
-                if k not in ("ev", "ts_us", "dur_us", "thread")
+                if k not in ("ev", "ts_us", "dur_us", "thread", "name")
             },
         }
         if dur is not None:

@@ -33,7 +33,6 @@ a no-op, ``enabled`` False), so the hot path costs one attribute check.
 
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import json
 import time
@@ -58,6 +57,7 @@ EV_VERDICT = "verdict"  # per-thread detectability verdict (recovery)
 EV_SCHED = "sched"  # MultiThreadDriver interleaving action
 EV_REQUEST = "request"  # serving-tier request lifecycle (arrive/admit/serve)
 EV_FABRIC = "fabric"  # periodic per-shard gauge sample (backlog, epochs)
+EV_SPAN = "span"  # a program span closed: name, dur_us (FabricObserver.span)
 
 
 class NullRecorder:
@@ -71,10 +71,6 @@ class NullRecorder:
 
     def event(self, ev: str, **fields: Any) -> None:
         return None
-
-    @contextlib.contextmanager
-    def span(self, ev: str, **fields: Any):
-        yield None
 
     def on_pwb(self, rel: str, tag: Optional[str]) -> None:
         return None
@@ -135,17 +131,6 @@ class TraceRecorder(NullRecorder):
         self.ring.append(rec)
         self._pending.append(rec)
         return rec
-
-    @contextlib.contextmanager
-    def span(self, ev: str, **fields: Any):
-        """Record ``ev`` as ONE event carrying its wall duration (closed at
-        exit, so the event's ``ts_us`` marks the END and ``dur_us`` spans
-        back — the Chrome exporter re-bases it to a begin timestamp)."""
-        t0 = time.perf_counter_ns()
-        try:
-            yield self
-        finally:
-            self.event(ev, dur_us=(time.perf_counter_ns() - t0) / 1e3, **fields)
 
     # -------------------------------------------------- persistence hooks
     def on_pwb(self, rel: str, tag: Optional[str]) -> None:

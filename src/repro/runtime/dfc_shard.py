@@ -141,6 +141,8 @@ from repro.obs import (
     EV_RETIRE,
     EV_VERDICT,
     NULL_OBS,
+    count,
+    recording,
 )
 from repro.core.jax_dfc import (
     KIND_CODES,
@@ -173,6 +175,16 @@ from repro.kernels.dfc_reduce.ops import (
 # runtime-level response kind: op rejected because its shard's announcement
 # lanes were full this phase — never applied, safe to re-announce.
 R_OVERFLOW = 4
+
+
+def _fetch(tree):
+    """Device-to-host copy of a pytree of arrays; counts its bytes
+    (``d2h_bytes``) while a profiler session records."""
+    out = jax.tree_util.tree_map(np.asarray, tree)
+    if recording():
+        count("d2h_bytes", sum(a.nbytes for a in jax.tree_util.tree_leaves(out)))
+    return out
+
 
 # ---------------------------------------------------------------------------
 # Per-side combiners (ISSUE 8, after Persistent Software Combining 2107.03492
@@ -1055,14 +1067,20 @@ class ShardedDFCRuntime:
         protocol identical to the serial schedule (same pwbs/pfences, merely
         re-timed), so deep pipelines never clobber an un-persisted response.
         """
-        valid = self._read_valid(thread)
-        n_op = 1 - (valid & 1)
-        if self._inflight:
-            old_tok = self._slot_tokens.get((thread, n_op), -1)
-            while old_tok >= 0 and self._chain_holding(thread, old_tok) is not None:
-                self._retire(self._inflight.popleft())
-        n_op, ann = self._announce_durable(thread, token, keys, ops, params)
-        self._register_live(thread, n_op, token, ann["keys"], ann["ops"], ann["params"])
+        with self.obs.span("fabric.announce"):
+            valid = self._read_valid(thread)
+            n_op = 1 - (valid & 1)
+            old_tok = (
+                self._slot_tokens.get((thread, n_op), -1) if self._inflight else -1
+            )
+            if old_tok >= 0 and self._chain_holding(thread, old_tok) is not None:
+                with self.obs.span("announce.retire_wait"):
+                    while self._chain_holding(thread, old_tok) is not None:
+                        self._retire(self._inflight.popleft())
+            n_op, ann = self._announce_durable(thread, token, keys, ops, params)
+            self._register_live(
+                thread, n_op, token, ann["keys"], ann["ops"], ann["params"]
+            )
 
     def _announce_durable(
         self, thread: int, token: int, keys, ops, params
@@ -1118,22 +1136,24 @@ class ShardedDFCRuntime:
             spans = [v for t, v in self._ring_spans.items() if t != thread]
             oldest = min((s0 for s0, _ in spans), default=self._ring_tail)
             if ring_has_room(slots, self._ring_tail, oldest, n):
-                # split-lane fabrics annotate each ring slot with its op's
-                # announcement lane (head/tail by target-shard structure),
-                # so lane-filtered drains (``ring_drain(..., lane=...)``)
-                # can feed a per-side combine dispatch straight off device
-                lane_col = (
-                    jnp.asarray(self._op_lanes_host(ops, self.route_host(keys)))
-                    if self.split_lanes
-                    else None
-                )
-                self.ring = ring_announce(
-                    self.ring,
-                    jnp.asarray(keys.astype(np.int32)),
-                    jnp.asarray(ops),
-                    jnp.asarray(params),
-                    lane_col,
-                )
+                with self.obs.span("announce.ring"):
+                    # split-lane fabrics annotate each ring slot with its
+                    # op's announcement lane (head/tail by target-shard
+                    # structure), so lane-filtered drains
+                    # (``ring_drain(..., lane=...)``) can feed a per-side
+                    # combine dispatch straight off device
+                    lane_col = (
+                        jnp.asarray(self._op_lanes_host(ops, self.route_host(keys)))
+                        if self.split_lanes
+                        else None
+                    )
+                    self.ring = ring_announce(
+                        self.ring,
+                        jnp.asarray(keys.astype(np.int32)),
+                        jnp.asarray(ops),
+                        jnp.asarray(params),
+                        lane_col,
+                    )
                 start = self._ring_tail
                 self._ring_tail += n
                 self._ring_spans[thread] = (start, n)
@@ -1200,27 +1220,43 @@ class ShardedDFCRuntime:
             "phases": int(counters[0]),
             "ops_combined": int(counters[1]),
         }
-        for i, leaf in enumerate(leaves):
-            arr = np.asarray(leaf)
-            buf = io.BytesIO()
-            np.save(buf, arr)
-            data = buf.getvalue()
-            rel = f"{slot}/leaf_{i}.npy"
-            digest = hashlib.blake2b(data, digest_size=16).digest()
-            if self._elide.get(rel) != digest:
-                self.fs.write(rel, data, tag="slot")
-                files.append(rel)
-                self._elide_pending[rel] = digest
-                self.obs.metrics.counter("elision_miss", shard=s)
-            else:
-                self.obs.metrics.counter("elision_hit", shard=s)
-            meta["leaves"].append(
-                {"file": f"leaf_{i}.npy", "shape": list(arr.shape), "dtype": str(arr.dtype)}
-            )
+        with self.obs.span("retire.serialize"):
+            for i, leaf in enumerate(leaves):
+                arr = np.asarray(leaf)
+                rel = f"{slot}/leaf_{i}.npy"
+                if self._save_leaf(rel, arr):
+                    files.append(rel)
+                meta["leaves"].append(
+                    {"file": f"leaf_{i}.npy", "shape": list(arr.shape), "dtype": str(arr.dtype)}
+                )
+        self._count_leaves(s, len(files), len(leaves) - len(files))
         rel = f"{slot}/meta.json"
         self.fs.write(rel, json.dumps(meta).encode(), tag="slot")
         files.append(rel)
         return files
+
+    def _save_leaf(self, rel: str, arr) -> bool:
+        """pwb one leaf's ``np.save`` bytes at ``rel`` unless the slot
+        already holds them durably; returns whether it was written."""
+        buf = io.BytesIO()
+        np.save(buf, arr)
+        data = buf.getvalue()
+        digest = hashlib.blake2b(data, digest_size=16).digest()
+        if self._elide.get(rel) == digest:
+            return False
+        self.fs.write(rel, data, tag="slot")
+        self._elide_pending[rel] = digest
+        return True
+
+    def _count_leaves(self, s: int, written: int, elided: int) -> None:
+        """Slot leaves written and elided for shard ``s``: the profiler
+        tally's ``slot_leaves_written`` / ``slot_leaves_elided``, and the
+        observer's per-shard counters of the same names."""
+        count("slot_leaves_written", written)
+        count("slot_leaves_elided", elided)
+        if self.obs.enabled:
+            self.obs.metrics.counter("slot_leaves_written", written, shard=s)
+            self.obs.metrics.counter("slot_leaves_elided", elided, shard=s)
 
     def _promote_elision(self) -> None:
         """Move leaf digests written since the last pfence into the elision
@@ -1333,23 +1369,18 @@ class ShardedDFCRuntime:
             "handoff": (LANE_HEAD, LANE_TAIL),
         }[mode]
         files: List[str] = []
+        written = elided = 0
         for lane in commit_lanes:
             target = int(lane_targets[lane])
             sdir = self._lane_slot_dir(s, lane, target - 2, nxt=True)
             if _LANE_WRITES_VALUES[kind][lane]:
-                arr = np.asarray(one.values)
-                buf = io.BytesIO()
-                np.save(buf, arr)
-                data = buf.getvalue()
                 rel = f"{sdir}/values.npy"
-                digest = hashlib.blake2b(data, digest_size=16).digest()
-                if self._elide.get(rel) != digest:
-                    self.fs.write(rel, data, tag="slot")
+                with self.obs.span("retire.serialize"):
+                    fresh = self._save_leaf(rel, np.asarray(one.values))
+                if fresh:
                     files.append(rel)
-                    self._elide_pending[rel] = digest
-                    self.obs.metrics.counter("elision_miss", shard=s)
-                else:
-                    self.obs.metrics.counter("elision_hit", shard=s)
+                written += fresh
+                elided += not fresh
             rec = {
                 "kind": kind,
                 "lane": _LANE_TAGS[lane],
@@ -1361,6 +1392,7 @@ class ShardedDFCRuntime:
             rel = f"{sdir}/rec.json"
             self.fs.write(rel, json.dumps(rec).encode(), tag="slot")
             files.append(rel)
+        self._count_leaves(s, written, elided)
         return files
 
     def _commit_lane_epochs(
@@ -1544,12 +1576,64 @@ class ShardedDFCRuntime:
         batch-by-batch, exactly like that many serial phases — padding
         batches touch no shard and cost no persistence op.
         """
-        assert self.fs is not None, "combine_phase needs a SimFS"
-        ready = self._collect_ready()
-        if not ready:
-            self.flush()
-            return []
+        with self.obs.span("combine"):
+            assert self.fs is not None, "combine_phase needs a SimFS"
+            with self.obs.span("combine.collect"):
+                ready = self._collect_ready()
+                payload = self._chain_payload(ready) if ready else None
+            if payload is None:
+                self.flush()
+                return []
+            dev_keys, dev_ops, dev_params, batches = payload
 
+            # stage 1: dispatch the chained device combine (async under jit)
+            with self.obs.span("combine.dispatch"):
+                (
+                    self.groups, self.meta, resp, out_kinds,
+                    states, epochs_before, epochs, phases_cum, ops_cum,
+                ) = hetero_multi_step(
+                    self.groups,
+                    jnp.asarray(self.table),
+                    jnp.stack(dev_keys),
+                    jnp.stack(dev_ops),
+                    jnp.stack(dev_params),
+                    self.meta,
+                    kinds=tuple(self.kinds),
+                    lanes=self.lanes,
+                    backend=self.backend,
+                    unroll=self.depth,
+                )
+            self._inflight.append({
+                "batches": batches, "resp": resp, "kinds": out_kinds,
+                "states": states, "epochs_before": epochs_before,
+                "epochs": epochs, "phases_cum": phases_cum, "ops_cum": ops_cum,
+                "repoch": self.r_epoch,
+            })
+            self.last_dispatch = [
+                tuple((seg["thread"], seg["token"]) for seg in info["threads"])
+                for info in batches
+                if info["threads"]
+            ]
+            if self.obs.enabled:
+                self.obs.event(
+                    EV_DISPATCH,
+                    batches=[
+                        [[seg["thread"], seg["token"]] for seg in info["threads"]]
+                        for info in batches
+                    ],
+                    inflight=len(self._inflight),
+                )
+            # stage 2: retire the oldest chains, in commit order, while the
+            # device combines — keep at most depth-1 chains in flight
+            while len(self._inflight) > self.depth - 1:
+                self._retire(self._inflight.popleft())
+            if self.obs.enabled:
+                self.obs.observe_fabric(self)
+            return [seg["thread"] for info in batches for seg in info["threads"]]
+
+    def _chain_payload(self, ready):
+        """The chain's padded device payload and its batches' host metadata:
+        ``(keys, ops, params, batches)``, one entry per batch."""
         if self.chain > 1:
             groups = [[r] for r in ready[: self.chain - 1]]
             tail = list(ready[self.chain - 1:])
@@ -1597,51 +1681,7 @@ class ShardedDFCRuntime:
                 {"threads": segs, "shard": self.route_host(host_keys),
                  "ops": host_ops}
             )
-
-        # stage 1: dispatch the chained device combine (async under jit)
-        (
-            self.groups, self.meta, resp, out_kinds,
-            states, epochs_before, epochs, phases_cum, ops_cum,
-        ) = hetero_multi_step(
-            self.groups,
-            jnp.asarray(self.table),
-            jnp.stack(dev_keys),
-            jnp.stack(dev_ops),
-            jnp.stack(dev_params),
-            self.meta,
-            kinds=tuple(self.kinds),
-            lanes=self.lanes,
-            backend=self.backend,
-            unroll=self.depth,
-        )
-        self._inflight.append({
-            "batches": batches, "resp": resp, "kinds": out_kinds,
-            "states": states, "epochs_before": epochs_before,
-            "epochs": epochs, "phases_cum": phases_cum, "ops_cum": ops_cum,
-            "repoch": self.r_epoch,
-        })
-        self.last_dispatch = [
-            tuple((seg["thread"], seg["token"]) for seg in info["threads"])
-            for info in batches
-            if info["threads"]
-        ]
-        if self.obs.enabled:
-            self.obs.event(
-                EV_DISPATCH,
-                batches=[
-                    [[seg["thread"], seg["token"]] for seg in info["threads"]]
-                    for info in batches
-                ],
-                inflight=len(self._inflight),
-            )
-            self.obs.metrics.gauge("inflight_chains", len(self._inflight))
-        # stage 2: retire the oldest chains, in commit order, while the
-        # device combines — keep at most depth-1 chains in flight
-        while len(self._inflight) > self.depth - 1:
-            self._retire(self._inflight.popleft())
-        if self.obs.enabled:
-            self.obs.observe_fabric(self)
-        return [seg["thread"] for info in batches for seg in info["threads"]]
+        return dev_keys, dev_ops, dev_params, batches
 
     def _retire(self, fl: Dict[str, Any]) -> List[int]:
         """Persist + commit one dispatched chain, batch by batch: persist
@@ -1649,101 +1689,106 @@ class ShardedDFCRuntime:
         responses into the combined announcements, ONE pfence, then the
         per-shard two-increment epoch commits — identical durable schedule
         (and pwb/pfence counts) to that many serial phases."""
-        resp = np.asarray(fl["resp"])
-        kinds = np.asarray(fl["kinds"])
-        epochs = np.asarray(fl["epochs"])  # [B, S]
-        phases_cum = np.asarray(fl["phases_cum"])
-        ops_cum = np.asarray(fl["ops_cum"])
-        prev_epochs = np.asarray(fl["epochs_before"])
-        # one device->host fetch per stacked leaf (not per shard slice)
-        states_np = {
-            k: jax.tree_util.tree_map(np.asarray, st)
-            for k, st in fl["states"].items()
-        }
+        with self.obs.span("retire"):
+            # one device->host fetch per stacked leaf (not per shard slice)
+            with self.obs.span("retire.fetch"):
+                (resp, kinds, epochs, phases_cum, ops_cum, prev_epochs,
+                 states_np) = _fetch((
+                    fl["resp"], fl["kinds"], fl["epochs"], fl["phases_cum"],
+                    fl["ops_cum"], fl["epochs_before"], fl["states"],
+                ))  # epochs: [B, S]
 
-        def batch_shard_state(b, s):
-            k, r = self.kinds[s], self._row(s)
-            return jax.tree_util.tree_map(lambda leaf: leaf[b, r], states_np[k])
-        retired = []
-        for b, info in enumerate(fl["batches"]):
-            e_b = epochs[b]
-            touched = [int(s) for s in np.nonzero(e_b != prev_epochs)[0]]
-            if not info["threads"] and not touched:
-                continue  # chain-padding pass-through: no durable work
-            shard = info["shard"]
-            ops_host = info["ops"]
-            kinds_row = kinds[b][: len(ops_host)]
-            # per-side lanes: plan each touched split shard's commit (which
-            # lane(s) advance, or a handoff) from the batch's op mix + the
-            # post-phase counters, BEFORE any durable write of this phase
-            plans: Dict[int, Tuple[str, List[int]]] = {}
-            for s in touched:
-                if self._is_split(s):
-                    plans[s] = self._plan_lane_commit(
-                        s, ops_host, kinds_row, shard, batch_shard_state(b, s)
-                    )
-            files: List[str] = []
-            for s in touched:
-                if s in plans:
-                    files += self._persist_split_shard(
-                        s, plans[s][0], plans[s][1],
-                        state=batch_shard_state(b, s),
-                        counters=(phases_cum[b][s], ops_cum[b][s]),
+            def batch_shard_state(b, s):
+                k, r = self.kinds[s], self._row(s)
+                return jax.tree_util.tree_map(lambda leaf: leaf[b, r], states_np[k])
+            retired = []
+            for b, info in enumerate(fl["batches"]):
+                e_b = epochs[b]
+                touched = [int(s) for s in np.nonzero(e_b != prev_epochs)[0]]
+                if not info["threads"] and not touched:
+                    continue  # chain-padding pass-through: no durable work
+                shard = info["shard"]
+                ops_host = info["ops"]
+                kinds_row = kinds[b][: len(ops_host)]
+                # per-side lanes: plan each touched split shard's commit (which
+                # lane(s) advance, or a handoff) from the batch's op mix + the
+                # post-phase counters, BEFORE any durable write of this phase
+                plans: Dict[int, Tuple[str, List[int]]] = {}
+                for s in touched:
+                    if self._is_split(s):
+                        plans[s] = self._plan_lane_commit(
+                            s, ops_host, kinds_row, shard, batch_shard_state(b, s)
+                        )
+                files: List[str] = []
+                for s in touched:
+                    if s in plans:
+                        files += self._persist_split_shard(
+                            s, plans[s][0], plans[s][1],
+                            state=batch_shard_state(b, s),
+                            counters=(phases_cum[b][s], ops_cum[b][s]),
+                        )
+                    else:
+                        files += self._persist_shard(
+                            s,
+                            int(e_b[s]),
+                            state=batch_shard_state(b, s),
+                            counters=(phases_cum[b][s], ops_cum[b][s]),
+                        )
+                fallback = e_b[shard]  # per-op commit target (its shard)
+                if self.split_lanes:
+                    targets, op_lanes = self._lane_targets_per_op(
+                        ops_host, shard, plans, fallback
                     )
                 else:
-                    files += self._persist_shard(
-                        s,
-                        int(e_b[s]),
-                        state=batch_shard_state(b, s),
-                        counters=(phases_cum[b][s], ops_cum[b][s]),
+                    targets = [int(e) for e in fallback]
+                    op_lanes = None
+                with self.obs.span("retire.responses"):
+                    for seg in info["threads"]:
+                        sl = slice(seg["off"], seg["off"] + seg["n"])
+                        ann = self._read_ann(seg["thread"], seg["slot"])
+                        ann["val"] = {
+                            "resp": [float(v) for v in resp[b][sl]],
+                            "kinds": [int(k) for k in kinds[b][sl]],
+                            "shards": [int(s) for s in shard[sl]],
+                            "targets": list(targets[sl]),
+                            "repoch": fl["repoch"],
+                        }
+                        if op_lanes is not None:
+                            ann["val"]["lanes"] = list(op_lanes[sl])
+                        rel = self._ann_path(seg["thread"], seg["slot"])
+                        self.fs.write(rel, json.dumps(ann).encode(), tag="resp")
+                        files.append(rel)
+                        retired.append(seg["thread"])
+                self.fs.fsync(files, tag="phase")  # ONE pfence for slots + responses
+                self._promote_elision()
+                self._commit_epochs(touched, plans, e_b)
+                if self.obs.enabled:
+                    self.obs.event(
+                        EV_RETIRE,
+                        batch=b,
+                        threads=[
+                            [seg["thread"], seg["token"]] for seg in info["threads"]
+                        ],
+                        touched=touched,
+                        files=len(files),
                     )
-            fallback = e_b[shard]  # per-op commit target (its shard)
-            if self.split_lanes:
-                targets, op_lanes = self._lane_targets_per_op(
-                    ops_host, shard, plans, fallback
-                )
-            else:
-                targets = [int(e) for e in fallback]
-                op_lanes = None
-            for seg in info["threads"]:
-                sl = slice(seg["off"], seg["off"] + seg["n"])
-                ann = self._read_ann(seg["thread"], seg["slot"])
-                ann["val"] = {
-                    "resp": [float(v) for v in resp[b][sl]],
-                    "kinds": [int(k) for k in kinds[b][sl]],
-                    "shards": [int(s) for s in shard[sl]],
-                    "targets": list(targets[sl]),
-                    "repoch": fl["repoch"],
-                }
-                if op_lanes is not None:
-                    ann["val"]["lanes"] = list(op_lanes[sl])
-                rel = self._ann_path(seg["thread"], seg["slot"])
-                self.fs.write(rel, json.dumps(ann).encode(), tag="resp")
-                files.append(rel)
-                retired.append(seg["thread"])
-            self.fs.fsync(files, tag="phase")  # ONE pfence for slots + responses
-            self._promote_elision()
-            for s in touched:  # per-shard two-increment epoch commit
+                prev_epochs = e_b
+            return retired
+
+    def _commit_epochs(self, touched, plans, targets) -> None:
+        """Each touched shard's two-increment epoch commit, in shard order:
+        odd write, fsync, even write (``_commit_lane_epochs`` for a split
+        shard, by its plan)."""
+        with self.obs.span("retire.epoch_commit"):
+            for s in touched:
                 if s in plans:
                     self._commit_lane_epochs(s, plans[s][0], plans[s][1])
                     continue
-                e = int(e_b[s])
+                e = int(targets[s])
                 self.fs.write(self._epoch_path(s), str(e - 1).encode(), tag="epoch")
                 self.fs.fsync([self._epoch_path(s)], tag="epoch")
                 self.fs.write(self._epoch_path(s), str(e).encode(), tag="epoch")
                 self.obs.event(EV_EPOCH, shard=s, epoch=e)
-            if self.obs.enabled:
-                self.obs.event(
-                    EV_RETIRE,
-                    batch=b,
-                    threads=[
-                        [seg["thread"], seg["token"]] for seg in info["threads"]
-                    ],
-                    touched=touched,
-                    files=len(files),
-                )
-            prev_epochs = e_b
-        return retired
 
     def flush(self) -> List[int]:
         """Retire every in-flight chain, oldest first (pipelined mode):
@@ -1880,16 +1925,12 @@ class ShardedDFCRuntime:
             )
 
         # fetch the intent log: one device->host transfer per stacked leaf
-        resp_np = np.asarray(resp)
-        kinds_np = np.asarray(out_kinds)
-        epochs = np.asarray(intents.epoch)  # [K, S]
-        phases_cum = np.asarray(intents.phases_cum)
-        ops_cum = np.asarray(intents.ops_cum)
-        prev_epochs = np.asarray(epochs_before)
-        states_np = {
-            k: jax.tree_util.tree_map(np.asarray, st)
-            for k, st in states.items()
-        }
+        with self.obs.span("retire.fetch"):
+            (resp_np, kinds_np, epochs, phases_cum, ops_cum, prev_epochs,
+             states_np) = _fetch((
+                resp, out_kinds, intents.epoch, intents.phases_cum,
+                intents.ops_cum, epochs_before, states,
+            ))  # epochs: [K, S]
 
         def phase_shard_state(j, s):
             k, r = self.kinds[s], self._row(s)
@@ -1942,29 +1983,22 @@ class ShardedDFCRuntime:
             else:
                 targets = [int(e) for e in fallback]
                 op_lanes = None
-            ann["val"] = {
-                "resp": [float(v) for v in resp_np[j][:n]],
-                "kinds": [int(k) for k in kinds_row],
-                "shards": [int(s) for s in shard],
-                "targets": list(targets),
-                "repoch": self.r_epoch,
-            }
-            if op_lanes is not None:
-                ann["val"]["lanes"] = list(op_lanes)
-            rel = self._ann_path(thread, slot)
-            self.fs.write(rel, json.dumps(ann).encode(), tag="resp")
+            with self.obs.span("retire.responses"):
+                ann["val"] = {
+                    "resp": [float(v) for v in resp_np[j][:n]],
+                    "kinds": [int(k) for k in kinds_row],
+                    "shards": [int(s) for s in shard],
+                    "targets": list(targets),
+                    "repoch": self.r_epoch,
+                }
+                if op_lanes is not None:
+                    ann["val"]["lanes"] = list(op_lanes)
+                rel = self._ann_path(thread, slot)
+                self.fs.write(rel, json.dumps(ann).encode(), tag="resp")
             files.append(rel)
             self.fs.fsync(files, tag="phase")  # ONE pfence for slots + responses
             self._promote_elision()
-            for s in touched:  # per-shard two-increment epoch commit
-                if s in plans:
-                    self._commit_lane_epochs(s, plans[s][0], plans[s][1])
-                    continue
-                e = int(e_j[s])
-                self.fs.write(self._epoch_path(s), str(e - 1).encode(), tag="epoch")
-                self.fs.fsync([self._epoch_path(s)], tag="epoch")
-                self.fs.write(self._epoch_path(s), str(e).encode(), tag="epoch")
-                self.obs.event(EV_EPOCH, shard=s, epoch=e)
+            self._commit_epochs(touched, plans, e_j)
             if self.obs.enabled:
                 self.obs.event(
                     EV_DRAIN,
@@ -2009,52 +2043,53 @@ class ShardedDFCRuntime:
         announcements); returns ``None`` only while the batch is genuinely
         pending (announced and not yet retired, or not yet announced).
         """
+        with self.obs.span("fabric.read_responses"):
 
-        def _lane_view(val: Dict[str, Any], tok: int):
-            out = dict(val, token=tok)
-            if lane is None:
+            def _lane_view(val: Dict[str, Any], tok: int):
+                out = dict(val, token=tok)
+                if lane is None:
+                    return out
+                lanes = val.get("lanes")
+                if lanes is None:
+                    lanes = [LANE_NONE] * len(val.get("kinds", []))
+                idx = [i for i, ln in enumerate(lanes) if ln == lane]
+                for key in ("resp", "kinds", "shards", "targets", "lanes"):
+                    if key in out and isinstance(out[key], list):
+                        out[key] = [out[key][i] for i in idx]
                 return out
-            lanes = val.get("lanes")
-            if lanes is None:
-                lanes = [LANE_NONE] * len(val.get("kinds", []))
-            idx = [i for i, ln in enumerate(lanes) if ln == lane]
-            for key in ("resp", "kinds", "shards", "targets", "lanes"):
-                if key in out and isinstance(out[key], list):
-                    out[key] = [out[key][i] for i in idx]
-            return out
 
-        v = self._read_valid(thread)
-        if token is None:
-            ann = self._read_ann(thread, v & 1)
-            if ann.get("val") is BOT:
-                return None
-            return _lane_view(ann["val"], ann["token"])
-        held = []
-        for slot in (v & 1, 1 - (v & 1)):
-            ann = self._read_ann(thread, slot)
-            t = ann.get("token", -1)
-            if t == token:
+            v = self._read_valid(thread)
+            if token is None:
+                ann = self._read_ann(thread, v & 1)
                 if ann.get("val") is BOT:
-                    return None  # announced, not yet combined/retired
+                    return None
                 return _lane_view(ann["val"], ann["token"])
-            if t >= 0:
-                held.append(t)
-        # Staleness: per-thread tokens are MONOTONE, so a requested token
-        # below the NEWEST retained one provably predates the retained
-        # slot(s) — either it was announced and its record has been
-        # overwritten, or it was skipped and can never be announced now.
-        # (Comparing against min(held) missed the gap case — a token between
-        # the two retained ones, or below the only retained one while the
-        # other slot is still unannounced — and silently returned None,
-        # indistinguishable from "pending", so pollers spun forever.)
-        if held and token < max(held):
-            raise StaleTokenError(
-                f"thread {thread} token {token} predates retained "
-                f"announcement slot(s) (tokens held: {sorted(held)}); its "
-                "response record was overwritten or never announced — read "
-                "responses before announcing two successor batches"
-            )
-        return None
+            held = []
+            for slot in (v & 1, 1 - (v & 1)):
+                ann = self._read_ann(thread, slot)
+                t = ann.get("token", -1)
+                if t == token:
+                    if ann.get("val") is BOT:
+                        return None  # announced, not yet combined/retired
+                    return _lane_view(ann["val"], ann["token"])
+                if t >= 0:
+                    held.append(t)
+            # Staleness: per-thread tokens are MONOTONE, so a requested token
+            # below the NEWEST retained one provably predates the retained
+            # slot(s) — either it was announced and its record has been
+            # overwritten, or it was skipped and can never be announced now.
+            # (Comparing against min(held) missed the gap case — a token between
+            # the two retained ones, or below the only retained one while the
+            # other slot is still unannounced — and silently returned None,
+            # indistinguishable from "pending", so pollers spun forever.)
+            if held and token < max(held):
+                raise StaleTokenError(
+                    f"thread {thread} token {token} predates retained "
+                    f"announcement slot(s) (tokens held: {sorted(held)}); its "
+                    "response record was overwritten or never announced — read "
+                    "responses before announcing two successor batches"
+                )
+            return None
 
     # ----------------------------------------------------------- resharding
     def _snapshot_donor(self, s: int, op: str) -> None:
@@ -2301,286 +2336,287 @@ class ShardedDFCRuntime:
         history (its durable responses are readable via
         ``read_responses(t, token=...)``) and is not reported.
         """
-        # Attach the observer FIRST so recovery's own repair writes join the
-        # durable timeline the pre-crash incarnation left behind (the
-        # recorder continues the sidecar's sequence numbering).
-        obs = obs if obs is not None else NULL_OBS
-        if obs.enabled:
-            fs.obs = obs
-            obs.event(EV_RECOVER, stage="begin")
+        with (obs or NULL_OBS).span("fabric.recover"):
+            # Attach the observer FIRST so recovery's own repair writes join the
+            # durable timeline the pre-crash incarnation left behind (the
+            # recorder continues the sidecar's sequence numbering).
+            obs = obs if obs is not None else NULL_OBS
+            if obs.enabled:
+                fs.obs = obs
+                obs.event(EV_RECOVER, stage="begin")
 
-        # --- routing epoch: round odd up (finish the second increment)
-        raw = fs.read(cls._REPOCH_PATH)
-        repoch = int(raw.decode()) if raw else 0
-        if repoch % 2 == 1:
-            repoch += 1
-            fs.write(cls._REPOCH_PATH, str(repoch).encode(), tag="recovery")
-            fs.fsync([cls._REPOCH_PATH], tag="recovery")
+            # --- routing epoch: round odd up (finish the second increment)
+            raw = fs.read(cls._REPOCH_PATH)
+            repoch = int(raw.decode()) if raw else 0
+            if repoch % 2 == 1:
+                repoch += 1
+                fs.write(cls._REPOCH_PATH, str(repoch).encode(), tag="recovery")
+                fs.fsync([cls._REPOCH_PATH], tag="recovery")
 
-        # --- adopt the committed routing record, if any
-        kinds = [kind] * n_shards if isinstance(kind, str) else list(kind)
-        active_slot = f"routing/slot{(repoch // 2) % 2}.json"
-        rec_raw = fs.read(active_slot)
-        if rec_raw:
-            rec = json.loads(rec_raw.decode())
-            kinds = list(rec["kinds"])
-            n_shards = int(rec["n_shards"])
-            n_buckets = int(rec["n_buckets"])
-            capacity = int(rec.get("capacity", capacity))
-            lanes = int(rec.get("lanes", lanes))
-            split_lanes = bool(rec.get("split_lanes", split_lanes))
-            table = np.asarray(rec["table"], np.int32)
+            # --- adopt the committed routing record, if any
+            kinds = [kind] * n_shards if isinstance(kind, str) else list(kind)
+            active_slot = f"routing/slot{(repoch // 2) % 2}.json"
+            rec_raw = fs.read(active_slot)
+            if rec_raw:
+                rec = json.loads(rec_raw.decode())
+                kinds = list(rec["kinds"])
+                n_shards = int(rec["n_shards"])
+                n_buckets = int(rec["n_buckets"])
+                capacity = int(rec.get("capacity", capacity))
+                lanes = int(rec.get("lanes", lanes))
+                split_lanes = bool(rec.get("split_lanes", split_lanes))
+                table = np.asarray(rec["table"], np.int32)
 
-        # --- resolve an interrupted reshard via its intent record
-        intent_raw = fs.read(cls._INTENT_PATH)
-        if intent_raw:
-            intent = json.loads(intent_raw.decode())
-            if intent["target_repoch"] <= repoch:
-                # committed: roll the touched shards' cEpochs forward (their
-                # slot data was pfenced before the rEpoch commit).  Split
-                # shards record a ``[eH, eT]`` lane pair; roll each
-                # component forward and keep the pair in one atomic file.
-                for sid_str, tgt in intent.get("target_epochs", {}).items():
-                    p = f"shard_{int(sid_str)}/cEpoch"
-                    raw_e = fs.read(p)
-                    if isinstance(tgt, list):
-                        txt = raw_e.decode() if raw_e else ""
-                        cur = (
-                            json.loads(txt)
-                            if txt.lstrip().startswith("[")
-                            else [0, int(txt)] if txt else [0, 0]
-                        )
-                        new = [max(int(cur[i]), int(tgt[i])) for i in (0, 1)]
-                        if new != [int(cur[0]), int(cur[1])]:
-                            fs.write(p, json.dumps(new).encode(), tag="recovery")
+            # --- resolve an interrupted reshard via its intent record
+            intent_raw = fs.read(cls._INTENT_PATH)
+            if intent_raw:
+                intent = json.loads(intent_raw.decode())
+                if intent["target_repoch"] <= repoch:
+                    # committed: roll the touched shards' cEpochs forward (their
+                    # slot data was pfenced before the rEpoch commit).  Split
+                    # shards record a ``[eH, eT]`` lane pair; roll each
+                    # component forward and keep the pair in one atomic file.
+                    for sid_str, tgt in intent.get("target_epochs", {}).items():
+                        p = f"shard_{int(sid_str)}/cEpoch"
+                        raw_e = fs.read(p)
+                        if isinstance(tgt, list):
+                            txt = raw_e.decode() if raw_e else ""
+                            cur = (
+                                json.loads(txt)
+                                if txt.lstrip().startswith("[")
+                                else [0, int(txt)] if txt else [0, 0]
+                            )
+                            new = [max(int(cur[i]), int(tgt[i])) for i in (0, 1)]
+                            if new != [int(cur[0]), int(cur[1])]:
+                                fs.write(p, json.dumps(new).encode(), tag="recovery")
+                                fs.fsync([p], tag="recovery")
+                            continue
+                        cur = int(raw_e.decode()) if raw_e else 0
+                        if cur < int(tgt):
+                            fs.write(p, str(int(tgt)).encode(), tag="recovery")
                             fs.fsync([p], tag="recovery")
-                        continue
-                    cur = int(raw_e.decode()) if raw_e else 0
-                    if cur < int(tgt):
-                        fs.write(p, str(int(tgt)).encode(), tag="recovery")
-                        fs.fsync([p], tag="recovery")
-            else:
-                # aborted: routing and shard epochs are still pre-reshard;
-                # drop the half-written inactive routing slot
-                fs.delete(f"routing/slot{(repoch // 2 + 1) % 2}.json")
-            fs.delete(cls._INTENT_PATH)
-
-        rt = cls(
-            kinds, n_shards, capacity, lanes,
-            backend=backend, fs=fs, n_threads=n_threads,
-            n_buckets=n_buckets, table=table,
-            pipeline=pipeline, depth=depth, chain=chain, ring_slots=ring_slots,
-            split_lanes=split_lanes, obs=obs,
-        )
-        rt.r_epoch = repoch
-
-        shard_states = []
-        phases = np.zeros((n_shards,), np.int32)
-        ops_combined = np.zeros((n_shards,), np.int32)
-        committed_epochs = np.zeros((n_shards,), np.int64)
-        committed_lane_epochs: Dict[int, List[int]] = {}
-        for s in range(n_shards):
-            fresh = STRUCTS[kinds[s]].init(capacity)
-            if rt._is_split(s):
-                # --- split shard: round each lane's odd epoch component up
-                # (the composite pair file keeps a handoff's two components
-                # atomic — a crash can never land between them), reload the
-                # two ACTIVE lane records, and reassemble one state
-                pair = rt._read_lane_epochs(s)
-                if any(e % 2 == 1 for e in pair):
-                    pair = [e + (e % 2) for e in pair]
-                    fs.write(
-                        rt._epoch_path(s), json.dumps(pair).encode(),
-                        tag="recovery",
-                    )
-                    fs.fsync([rt._epoch_path(s)], tag="recovery")
-                committed_lane_epochs[s] = list(pair)
-                rt.lane_epochs[s] = list(pair)
-                committed_epochs[s] = pair[0] + pair[1]
-                recs: List[Optional[Dict[str, Any]]] = [None, None]
-                live = set()
-                for lane in (LANE_HEAD, LANE_TAIL):
-                    adir = rt._lane_slot_dir(s, lane, pair[lane], nxt=False)
-                    rrel = f"{adir}/rec.json"
-                    raw_rec = fs.read_durable(rrel)
-                    if raw_rec:
-                        recs[lane] = json.loads(raw_rec.decode())
-                        live.add(rrel)
-                        if _LANE_WRITES_VALUES[kinds[s]][lane]:
-                            live.add(f"{adir}/values.npy")
-                f_ends = np.asarray(fresh.ends)[0]
-                h = int(recs[LANE_HEAD]["ctr"]) if recs[LANE_HEAD] else int(f_ends[0])
-                t = int(recs[LANE_TAIL]["ctr"]) if recs[LANE_TAIL] else int(f_ends[1])
-                # values: the lane whose record carries the larger ``phases``
-                # commit-sequence number holds the chronologically last
-                # committed copy (each values-owning lane re-validates its
-                # slot's values at every commit, elided when identical)
-                values = np.asarray(fresh.values)
-                best = (-1, None)
-                for lane in (LANE_HEAD, LANE_TAIL):
-                    r = recs[lane]
-                    if r is None or not _LANE_WRITES_VALUES[kinds[s]][lane]:
-                        continue
-                    if int(r.get("phases", 0)) > best[0]:
-                        adir = rt._lane_slot_dir(s, lane, pair[lane], nxt=False)
-                        best = (int(r.get("phases", 0)), f"{adir}/values.npy")
-                if best[1] is not None:
-                    raw_v = fs.read_durable(best[1])
-                    if raw_v:
-                        values = np.load(io.BytesIO(raw_v))
-                shard_states.append(
-                    fresh.__class__(
-                        values=jnp.asarray(values),
-                        ends=jnp.asarray([[h, t], [h, t]], jnp.int32),
-                        epoch=jnp.asarray(pair[0] + pair[1], jnp.int32),
-                    )
-                )
-                phases[s] = max(
-                    int(r.get("phases", 0)) for r in recs if r is not None
-                ) if any(r is not None for r in recs) else 0
-                ops_combined[s] = max(
-                    int(r.get("ops_combined", 0)) for r in recs if r is not None
-                ) if any(r is not None for r in recs) else 0
-                # GC: drop partial lane-slot writes of the interrupted phase
-                for lane in (LANE_HEAD, LANE_TAIL):
-                    for p in (0, 1):
-                        d = f"shard_{s}/lane{_LANE_TAGS[lane]}{p}"
-                        for rel in list(fs.listdir(d)):
-                            if rel not in live:
-                                fs.delete(rel)
-                continue
-            epoch = rt._read_shard_epoch(s)
-            if epoch % 2 == 1:  # crashed between the two increments
-                epoch += 1
-                fs.write(rt._epoch_path(s), str(epoch).encode(), tag="recovery")
-                fs.fsync([rt._epoch_path(s)], tag="recovery")
-            committed_epochs[s] = epoch
-            active = rt._slot_dir(s, epoch, nxt=False)
-            inactive = rt._slot_dir(s, epoch, nxt=True)
-            meta_raw = fs.read_durable(f"{active}/meta.json")
-            live = {f"{active}/meta.json"}
-            if meta_raw:
-                meta = json.loads(meta_raw.decode())
-                live |= {f"{active}/{e['file']}" for e in meta["leaves"]}
-                leaves = [
-                    np.load(io.BytesIO(fs.read_durable(f"{active}/{e['file']}")))
-                    for e in meta["leaves"]
-                ]
-                treedef = jax.tree_util.tree_structure(fresh)
-                shard_states.append(
-                    jax.tree_util.tree_unflatten(
-                        treedef, [jnp.asarray(leaf) for leaf in leaves]
-                    )
-                )
-                phases[s] = meta.get("phases", 0)
-                ops_combined[s] = meta.get("ops_combined", 0)
-            else:
-                shard_states.append(fresh)
-            # GC: drop partial writes of the interrupted phase
-            for rel in list(fs.listdir(active)) + list(fs.listdir(inactive)):
-                if rel not in live:
-                    fs.delete(rel)
-
-        rt.groups = {
-            k: stack_shards([shard_states[s] for s in ids])
-            for k, ids in _group_ids(tuple(kinds)).items()
-        }
-        rt.meta = {
-            "phases": jnp.asarray(phases),
-            "ops_combined": jnp.asarray(ops_combined),
-            "kind": jnp.asarray([KIND_CODES[k] for k in kinds], jnp.int32),
-        }
-
-        def _slot_verdicts(ann) -> Tuple[List[OpVerdict], bool]:
-            """Per-op verdicts of one announcement record + whether the
-            record's phase fully committed (every target epoch reached).
-            Split-lane ops carry their LANE's target: committed iff that
-            lane's composite-epoch component reached it — the other lane's
-            progress neither commits nor rolls back this op."""
-            verdicts: List[OpVerdict] = []
-            val = ann.get("val")
-            n_ops = len(ann.get("ops", []))
-            if val is BOT:
-                return [OpVerdict(applied=False) for _ in range(n_ops)], False
-            op_lanes = val.get("lanes")
-            fully = True
-            for i in range(n_ops):
-                s = val["shards"][i]
-                k = val["kinds"][i]
-                ln = op_lanes[i] if op_lanes is not None else LANE_NONE
-                if ln != LANE_NONE and s in committed_lane_epochs:
-                    committed = committed_lane_epochs[s][ln] >= val["targets"][i]
                 else:
-                    committed = committed_epochs[s] >= val["targets"][i]
-                fully = fully and bool(committed)
-                applied = bool(committed) and k != R_OVERFLOW and k != R_NONE
-                verdicts.append(
-                    OpVerdict(
-                        applied=applied,
-                        kind=k if committed else None,
-                        resp=val["resp"][i] if committed else None,
-                        shard=s,
-                    )
-                )
-            return verdicts, fully
+                    # aborted: routing and shard epochs are still pre-reshard;
+                    # drop the half-written inactive routing slot
+                    fs.delete(f"routing/slot{(repoch // 2 + 1) % 2}.json")
+                fs.delete(cls._INTENT_PATH)
 
-        report: Dict[int, Dict[str, Any]] = {}
-        for t in range(n_threads):
-            v = rt._read_valid(t)
-            lsb = v & 1
-            if (v >> 1) & 1 == 0:  # re-publish a half-written valid selector
-                fs.write(rt._valid_path(t), str(2 | lsb).encode(), tag="recovery")
-            ann = rt._read_ann(t, lsb)
-            if ann.get("token", -1) < 0:
-                report[t] = {"token": None, "ops": [], "prev": None}
-                continue
-            verdicts, _ = _slot_verdicts(ann)
-            # overlap-aware: the OLDER slot may hold an in-flight PREDECESSOR
-            # (combined by the pipeline, never retired or never committed).
-            # Only a SMALLER token qualifies (per-thread tokens are monotone):
-            # a larger one is an unpublished successor whose announce crashed
-            # before the valid flip — never announced, the thread re-runs it.
-            prev = None
-            pann = rt._read_ann(t, 1 - lsb)
-            ptok = pann.get("token", -1)
-            if 0 <= ptok < ann["token"] and pann.get("ops"):
-                pverdicts, pfully = _slot_verdicts(pann)
-                if not pfully:
-                    prev = {"token": ptok, "ops": pverdicts}
-            report[t] = {"token": ann["token"], "ops": verdicts, "prev": prev}
-            if ann.get("val") is BOT:
-                # still pending: re-stage it (ring re-filled from the durable
-                # mirror) so a post-recovery combine_phase can run unchanged
-                rt._register_live(
-                    t, lsb, ann["token"], ann["keys"], ann["ops"], ann["params"]
-                )
-        if obs.enabled:
-            # Extend the pre-crash durable trace prefix with the recovery
-            # timeline: one verdict event per announced thread, then flush
-            # the sidecar explicitly (a sanctioned host-side flush point —
-            # recovery has no pfence of its own to ride here).
-            for t, rep in report.items():
-                if rep["token"] is None:
-                    continue
-                obs.event(
-                    EV_VERDICT,
-                    thread=t,
-                    token=rep["token"],
-                    applied=[bool(v.applied) for v in rep["ops"]],
-                    prev_token=(rep["prev"] or {}).get("token"),
-                    prev_applied=[
-                        bool(v.applied) for v in (rep["prev"] or {}).get("ops", [])
-                    ],
-                )
-            obs.event(
-                EV_RECOVER,
-                stage="end",
-                repoch=repoch,
-                epochs=[int(e) for e in committed_epochs],
-                threads=sum(1 for r in report.values() if r["token"] is not None),
+            rt = cls(
+                kinds, n_shards, capacity, lanes,
+                backend=backend, fs=fs, n_threads=n_threads,
+                n_buckets=n_buckets, table=table,
+                pipeline=pipeline, depth=depth, chain=chain, ring_slots=ring_slots,
+                split_lanes=split_lanes, obs=obs,
             )
-            obs.flush()
-        return rt, report
+            rt.r_epoch = repoch
+
+            shard_states = []
+            phases = np.zeros((n_shards,), np.int32)
+            ops_combined = np.zeros((n_shards,), np.int32)
+            committed_epochs = np.zeros((n_shards,), np.int64)
+            committed_lane_epochs: Dict[int, List[int]] = {}
+            for s in range(n_shards):
+                fresh = STRUCTS[kinds[s]].init(capacity)
+                if rt._is_split(s):
+                    # --- split shard: round each lane's odd epoch component up
+                    # (the composite pair file keeps a handoff's two components
+                    # atomic — a crash can never land between them), reload the
+                    # two ACTIVE lane records, and reassemble one state
+                    pair = rt._read_lane_epochs(s)
+                    if any(e % 2 == 1 for e in pair):
+                        pair = [e + (e % 2) for e in pair]
+                        fs.write(
+                            rt._epoch_path(s), json.dumps(pair).encode(),
+                            tag="recovery",
+                        )
+                        fs.fsync([rt._epoch_path(s)], tag="recovery")
+                    committed_lane_epochs[s] = list(pair)
+                    rt.lane_epochs[s] = list(pair)
+                    committed_epochs[s] = pair[0] + pair[1]
+                    recs: List[Optional[Dict[str, Any]]] = [None, None]
+                    live = set()
+                    for lane in (LANE_HEAD, LANE_TAIL):
+                        adir = rt._lane_slot_dir(s, lane, pair[lane], nxt=False)
+                        rrel = f"{adir}/rec.json"
+                        raw_rec = fs.read_durable(rrel)
+                        if raw_rec:
+                            recs[lane] = json.loads(raw_rec.decode())
+                            live.add(rrel)
+                            if _LANE_WRITES_VALUES[kinds[s]][lane]:
+                                live.add(f"{adir}/values.npy")
+                    f_ends = np.asarray(fresh.ends)[0]
+                    h = int(recs[LANE_HEAD]["ctr"] if recs[LANE_HEAD] else f_ends[0])
+                    t = int(recs[LANE_TAIL]["ctr"] if recs[LANE_TAIL] else f_ends[1])
+                    # values: the lane whose record carries the larger ``phases``
+                    # commit-sequence number holds the chronologically last
+                    # committed copy (each values-owning lane re-validates its
+                    # slot's values at every commit, elided when identical)
+                    values = np.asarray(fresh.values)
+                    best = (-1, None)
+                    for lane in (LANE_HEAD, LANE_TAIL):
+                        r = recs[lane]
+                        if r is None or not _LANE_WRITES_VALUES[kinds[s]][lane]:
+                            continue
+                        if int(r.get("phases", 0)) > best[0]:
+                            adir = rt._lane_slot_dir(s, lane, pair[lane], nxt=False)
+                            best = (int(r.get("phases", 0)), f"{adir}/values.npy")
+                    if best[1] is not None:
+                        raw_v = fs.read_durable(best[1])
+                        if raw_v:
+                            values = np.load(io.BytesIO(raw_v))
+                    shard_states.append(
+                        fresh.__class__(
+                            values=jnp.asarray(values),
+                            ends=jnp.asarray([[h, t], [h, t]], jnp.int32),
+                            epoch=jnp.asarray(pair[0] + pair[1], jnp.int32),
+                        )
+                    )
+                    phases[s] = max(
+                        int(r.get("phases", 0)) for r in recs if r is not None
+                    ) if any(r is not None for r in recs) else 0
+                    ops_combined[s] = max(
+                        int(r.get("ops_combined", 0)) for r in recs if r is not None
+                    ) if any(r is not None for r in recs) else 0
+                    # GC: drop partial lane-slot writes of the interrupted phase
+                    for lane in (LANE_HEAD, LANE_TAIL):
+                        for p in (0, 1):
+                            d = f"shard_{s}/lane{_LANE_TAGS[lane]}{p}"
+                            for rel in list(fs.listdir(d)):
+                                if rel not in live:
+                                    fs.delete(rel)
+                    continue
+                epoch = rt._read_shard_epoch(s)
+                if epoch % 2 == 1:  # crashed between the two increments
+                    epoch += 1
+                    fs.write(rt._epoch_path(s), str(epoch).encode(), tag="recovery")
+                    fs.fsync([rt._epoch_path(s)], tag="recovery")
+                committed_epochs[s] = epoch
+                active = rt._slot_dir(s, epoch, nxt=False)
+                inactive = rt._slot_dir(s, epoch, nxt=True)
+                meta_raw = fs.read_durable(f"{active}/meta.json")
+                live = {f"{active}/meta.json"}
+                if meta_raw:
+                    meta = json.loads(meta_raw.decode())
+                    live |= {f"{active}/{e['file']}" for e in meta["leaves"]}
+                    leaves = [
+                        np.load(io.BytesIO(fs.read_durable(f"{active}/{e['file']}")))
+                        for e in meta["leaves"]
+                    ]
+                    treedef = jax.tree_util.tree_structure(fresh)
+                    shard_states.append(
+                        jax.tree_util.tree_unflatten(
+                            treedef, [jnp.asarray(leaf) for leaf in leaves]
+                        )
+                    )
+                    phases[s] = meta.get("phases", 0)
+                    ops_combined[s] = meta.get("ops_combined", 0)
+                else:
+                    shard_states.append(fresh)
+                # GC: drop partial writes of the interrupted phase
+                for rel in list(fs.listdir(active)) + list(fs.listdir(inactive)):
+                    if rel not in live:
+                        fs.delete(rel)
+
+            rt.groups = {
+                k: stack_shards([shard_states[s] for s in ids])
+                for k, ids in _group_ids(tuple(kinds)).items()
+            }
+            rt.meta = {
+                "phases": jnp.asarray(phases),
+                "ops_combined": jnp.asarray(ops_combined),
+                "kind": jnp.asarray([KIND_CODES[k] for k in kinds], jnp.int32),
+            }
+
+            def _slot_verdicts(ann) -> Tuple[List[OpVerdict], bool]:
+                """Per-op verdicts of one announcement record + whether the
+                record's phase fully committed (every target epoch reached).
+                Split-lane ops carry their LANE's target: committed iff that
+                lane's composite-epoch component reached it — the other lane's
+                progress neither commits nor rolls back this op."""
+                verdicts: List[OpVerdict] = []
+                val = ann.get("val")
+                n_ops = len(ann.get("ops", []))
+                if val is BOT:
+                    return [OpVerdict(applied=False) for _ in range(n_ops)], False
+                op_lanes = val.get("lanes")
+                fully = True
+                for i in range(n_ops):
+                    s = val["shards"][i]
+                    k = val["kinds"][i]
+                    ln = op_lanes[i] if op_lanes is not None else LANE_NONE
+                    if ln != LANE_NONE and s in committed_lane_epochs:
+                        committed = committed_lane_epochs[s][ln] >= val["targets"][i]
+                    else:
+                        committed = committed_epochs[s] >= val["targets"][i]
+                    fully = fully and bool(committed)
+                    applied = bool(committed) and k != R_OVERFLOW and k != R_NONE
+                    verdicts.append(
+                        OpVerdict(
+                            applied=applied,
+                            kind=k if committed else None,
+                            resp=val["resp"][i] if committed else None,
+                            shard=s,
+                        )
+                    )
+                return verdicts, fully
+
+            report: Dict[int, Dict[str, Any]] = {}
+            for t in range(n_threads):
+                v = rt._read_valid(t)
+                lsb = v & 1
+                if (v >> 1) & 1 == 0:  # re-publish a half-written valid selector
+                    fs.write(rt._valid_path(t), str(2 | lsb).encode(), tag="recovery")
+                ann = rt._read_ann(t, lsb)
+                if ann.get("token", -1) < 0:
+                    report[t] = {"token": None, "ops": [], "prev": None}
+                    continue
+                verdicts, _ = _slot_verdicts(ann)
+                # overlap-aware: the OLDER slot may hold an in-flight PREDECESSOR
+                # (combined by the pipeline, never retired or never committed).
+                # Only a SMALLER token qualifies (per-thread tokens are monotone):
+                # a larger one is an unpublished successor whose announce crashed
+                # before the valid flip — never announced, the thread re-runs it.
+                prev = None
+                pann = rt._read_ann(t, 1 - lsb)
+                ptok = pann.get("token", -1)
+                if 0 <= ptok < ann["token"] and pann.get("ops"):
+                    pverdicts, pfully = _slot_verdicts(pann)
+                    if not pfully:
+                        prev = {"token": ptok, "ops": pverdicts}
+                report[t] = {"token": ann["token"], "ops": verdicts, "prev": prev}
+                if ann.get("val") is BOT:
+                    # still pending: re-stage it (ring re-filled from the durable
+                    # mirror) so a post-recovery combine_phase can run unchanged
+                    rt._register_live(
+                        t, lsb, ann["token"], ann["keys"], ann["ops"], ann["params"]
+                    )
+            if obs.enabled:
+                # Extend the pre-crash durable trace prefix with the recovery
+                # timeline: one verdict event per announced thread, then flush
+                # the sidecar explicitly (a sanctioned host-side flush point —
+                # recovery has no pfence of its own to ride here).
+                for t, rep in report.items():
+                    if rep["token"] is None:
+                        continue
+                    obs.event(
+                        EV_VERDICT,
+                        thread=t,
+                        token=rep["token"],
+                        applied=[bool(v.applied) for v in rep["ops"]],
+                        prev_token=(rep["prev"] or {}).get("token"),
+                        prev_applied=[
+                            bool(v.applied) for v in (rep["prev"] or {}).get("ops", [])
+                        ],
+                    )
+                obs.event(
+                    EV_RECOVER,
+                    stage="end",
+                    repoch=repoch,
+                    epochs=[int(e) for e in committed_epochs],
+                    threads=sum(1 for r in report.values() if r["token"] is not None),
+                )
+                obs.flush()
+            return rt, report
 
     def replay_pending(self, report: Dict[int, Dict[str, Any]]) -> List[int]:
         """Re-announce exactly the not-applied ops of every thread (read back
@@ -2598,80 +2634,81 @@ class ShardedDFCRuntime:
         (``report[t]["prev"]``, pipelined path), its not-applied ops are
         replayed in a round of their own BEFORE the newest announcements, so
         per-thread op order survives the crash."""
+        with self.obs.span("fabric.replay_pending"):
 
-        def _redo(ann, verdicts):
-            if not ann.get("ops"):
-                return None
-            idx = [
-                i for i, v in enumerate(verdicts)
-                if not v.applied and v.kind != R_NONE
-            ]
-            if not idx:
-                return None
-            return (
-                [ann["keys"][i] for i in idx],
-                [ann["ops"][i] for i in idx],
-                [ann["params"][i] for i in idx],
-            )
+            def _redo(ann, verdicts):
+                if not ann.get("ops"):
+                    return None
+                idx = [
+                    i for i, v in enumerate(verdicts)
+                    if not v.applied and v.kind != R_NONE
+                ]
+                if not idx:
+                    return None
+                return (
+                    [ann["keys"][i] for i in idx],
+                    [ann["ops"][i] for i in idx],
+                    [ann["params"][i] for i in idx],
+                )
 
-        # snapshot both slots' durable records BEFORE any re-announcement
-        # flips the valid selectors
-        prev_round: List[Tuple[int, int, Tuple]] = []
-        newest_round: List[Tuple[int, int, Dict[str, Any], List[OpVerdict]]] = []
-        for t in sorted(report):
-            r = report[t]
-            lsb = self._read_valid(t) & 1
-            prev = r.get("prev")
-            if prev is not None:
-                pann = self._read_ann(t, 1 - lsb)
-                if pann.get("token", -1) == prev["token"]:
-                    redo = _redo(pann, prev["ops"])
-                    if redo is not None:
-                        prev_round.append((t, prev["token"], redo))
-            if r["token"] is None:
-                continue
-            ann = self._read_ann(t, lsb)
-            if _redo(ann, r["ops"]) is not None:
-                newest_round.append((t, r["token"], ann, r["ops"]))
-
-        replayed = set()
-        # round 1: in-flight predecessors, so per-thread op order survives
-        for t, token, (keys, ops, params) in prev_round:
-            self.announce(t, keys, ops, params, token=token)
-            replayed.add(t)
-        if prev_round:
-            self._drain()
-
-        # round 2: newest announcements.  A still-PENDING one (val BOT at
-        # recovery) may have been swept up by round 1's combining phase —
-        # the combiner takes every ready announcement — in which case it is
-        # now applied and committed, and only its R_OVERFLOW rejections
-        # (which never touch state) still need a replay.
-        for t, token, ann, verdicts in newest_round:
-            pre_combined = any(v.shard is not None for v in verdicts)
-            if not pre_combined:
-                val = self.read_responses(t, token=token)
-                if val is not None:
-                    idx = [
-                        i for i, k in enumerate(val["kinds"]) if k == R_OVERFLOW
-                    ]
-                    if not idx:
-                        continue
-                    self.announce(
-                        t,
-                        [ann["keys"][i] for i in idx],
-                        [ann["ops"][i] for i in idx],
-                        [ann["params"][i] for i in idx],
-                        token=token,
-                    )
-                    replayed.add(t)
+            # snapshot both slots' durable records BEFORE any re-announcement
+            # flips the valid selectors
+            prev_round: List[Tuple[int, int, Tuple]] = []
+            newest_round: List[Tuple[int, int, Dict[str, Any], List[OpVerdict]]] = []
+            for t in sorted(report):
+                r = report[t]
+                lsb = self._read_valid(t) & 1
+                prev = r.get("prev")
+                if prev is not None:
+                    pann = self._read_ann(t, 1 - lsb)
+                    if pann.get("token", -1) == prev["token"]:
+                        redo = _redo(pann, prev["ops"])
+                        if redo is not None:
+                            prev_round.append((t, prev["token"], redo))
+                if r["token"] is None:
                     continue
-            keys, ops, params = _redo(ann, verdicts)
-            self.announce(t, keys, ops, params, token=token)
-            replayed.add(t)
-        if replayed:
-            self._drain()
-        return sorted(replayed)
+                ann = self._read_ann(t, lsb)
+                if _redo(ann, r["ops"]) is not None:
+                    newest_round.append((t, r["token"], ann, r["ops"]))
+
+            replayed = set()
+            # round 1: in-flight predecessors, so per-thread op order survives
+            for t, token, (keys, ops, params) in prev_round:
+                self.announce(t, keys, ops, params, token=token)
+                replayed.add(t)
+            if prev_round:
+                self._drain()
+
+            # round 2: newest announcements.  A still-PENDING one (val BOT at
+            # recovery) may have been swept up by round 1's combining phase —
+            # the combiner takes every ready announcement — in which case it is
+            # now applied and committed, and only its R_OVERFLOW rejections
+            # (which never touch state) still need a replay.
+            for t, token, ann, verdicts in newest_round:
+                pre_combined = any(v.shard is not None for v in verdicts)
+                if not pre_combined:
+                    val = self.read_responses(t, token=token)
+                    if val is not None:
+                        idx = [
+                            i for i, k in enumerate(val["kinds"]) if k == R_OVERFLOW
+                        ]
+                        if not idx:
+                            continue
+                        self.announce(
+                            t,
+                            [ann["keys"][i] for i in idx],
+                            [ann["ops"][i] for i in idx],
+                            [ann["params"][i] for i in idx],
+                            token=token,
+                        )
+                        replayed.add(t)
+                        continue
+                keys, ops, params = _redo(ann, verdicts)
+                self.announce(t, keys, ops, params, token=token)
+                replayed.add(t)
+            if replayed:
+                self._drain()
+            return sorted(replayed)
 
     # -------------------------------------------------------------- helpers
     def shard_contents(self, s: int) -> List[float]:

@@ -25,6 +25,7 @@ from repro.obs import (
     EV_PFENCE,
     EV_PWB,
     EV_RECOVER,
+    EV_SPAN,
     EV_VERDICT,
     FabricObserver,
     Histogram,
@@ -144,6 +145,12 @@ def test_traced_pipelined_run_is_bit_identical(tmp_path):
     )
     for s in range(2):
         assert rt1.shard_contents(s) == rt2.shard_contents(s)
+    # the observer's spans land in the recorder with their durations
+    spans = [e for e in read_trace(obs.trace_path) if e["ev"] == EV_SPAN]
+    assert {e["name"] for e in spans} >= {
+        "fabric.announce", "combine", "retire", "retire.fetch", "retire.epoch_commit",
+    }
+    assert all(e["dur_us"] >= 0 for e in spans)
 
 
 def test_read_responses_and_stale_token_unchanged_by_tracing(tmp_path):
@@ -300,6 +307,10 @@ def test_chrome_trace_exporter(tmp_path):
     span, instant = doc
     assert span["ph"] == "X" and span["dur"] == 40 and span["ts"] == 60
     assert instant["ph"] == "i"
+    to_chrome_trace([{"seq": 2, "ts_us": 50, "ev": EV_SPAN, "name": "retire.fetch",
+                      "dur_us": 5}], tmp_path / "s.json")
+    (program_span,) = json.loads((tmp_path / "s.json").read_text())
+    assert program_span["name"] == "retire.fetch" and program_span["ts"] == 45
 
 
 def test_bridge_persist_stats(tmp_path):

@@ -12,6 +12,8 @@ fused phase-loop schedule twice — once untraced, once under a
      identical;
   4. recovery over the traced root EXTENDS the sidecar with per-thread
      verdict events, with trace seq numbers monotone across the reboot;
+  5. 1-3 hold as well for a drive under a ``jax.profiler`` session, which
+     switches the program's spans and counters on;
 
 then renders the ``fabric_top`` table from the sidecar as a smoke of the
 operator tooling.  Exits non-zero on any violation.
@@ -33,7 +35,13 @@ sys.path.insert(0, str(_ROOT / "src"))
 sys.path.insert(0, str(_ROOT / "tools"))
 
 from repro.checkpoint.dfc_checkpoint import SimFS  # noqa: E402
-from repro.obs import EV_VERDICT, FabricObserver, durable_digest, read_trace  # noqa: E402
+from repro.obs import (  # noqa: E402
+    EV_VERDICT,
+    FabricObserver,
+    durable_digest,
+    profile_totals,
+    read_trace,
+)
 from repro.runtime.dfc_shard import ShardedDFCRuntime  # noqa: E402
 
 import fabric_top  # noqa: E402
@@ -82,6 +90,16 @@ def _drive(root: Path, obs=None, kind=KIND, schedule=None):
     return fs, rt
 
 
+def _drive_profiled(root: Path, log_dir: Path):
+    import jax
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        return _drive(root)
+    finally:
+        jax.profiler.stop_trace()
+
+
 def main() -> int:
     base = Path(tempfile.mkdtemp(prefix="dfc_obs_smoke_"))
     failures = []
@@ -106,6 +124,19 @@ def main() -> int:
             failures.append(
                 f"durable state diverged: {d_plain} vs {d_traced}"
             )
+
+        # the program's spans and counters, switched on by a profiler
+        # session, are as pure as the flight recorder
+        fs_prof, _ = _drive_profiled(base / "profiled", base / "profile")
+        if fs_plain.pstats.as_dict() != fs_prof.pstats.as_dict():
+            failures.append(
+                f"profiled: per-tag pwb/pfence diverged: "
+                f"{fs_plain.pstats.as_dict()} vs {fs_prof.pstats.as_dict()}"
+            )
+        if d_plain != durable_digest(base / "profiled"):
+            failures.append("profiled: durable state diverged")
+        if "retire.fetch" not in profile_totals()["spans"]:
+            failures.append("profiled: no retire.fetch span in the session's tally")
 
         # the purity invariant is gated on the keyed-map kind too: the same
         # insert/lookup/delete/CAS schedule traced and untraced
