@@ -2,12 +2,13 @@
 
 A split (``split_lanes=True``) queue/deque shard commits its head-side and
 tail-side announcement lanes independently: a single-lane phase persists
-only that side's durable record and its half of the composite epoch pair,
-instead of the one-lane layout's shared counter pair + epoch + manifest.
-The win appears exactly under ARRIVAL SKEW — bursts that touch one side at
-a time (producers ahead of consumers, admission draining the head while
-arrivals land on the tail).  Drained balanced traffic fully eliminates in
-both layouts and must tie.
+only that side's durable record and its half of the composite epoch pair.
+The one-lane slot carries its counter pair and epoch inline in one
+``meta.json``, so the split layout no longer wins much: under ARRIVAL SKEW
+(bursts that touch one side at a time) a queue's head lane saves the
+values write of a dequeue burst and a deque ties; drained balanced traffic
+fully eliminates, and there the one-lane layout's single manifest pays
+less than the split layout's two lane records.
 
 Workload, per (kind, skew) cell on a one-shard fabric:
 
@@ -18,9 +19,9 @@ Workload, per (kind, skew) cell on a one-shard fabric:
 
 Each cell measures steady-state pwb/op, pfence/op and phases/s for
 ``split_lanes`` off vs on.  Script mode writes ``BENCH_split_combiner.json``
-(see docs/benchmarks.md) and exits non-zero if the two-lane layout fails to
-beat the one-lane pwb/op on any skewed cell — the regression gate CI runs
-via ``--smoke``.
+(see docs/benchmarks.md) and exits non-zero if either layout's pwb per
+measured phase differs from ``PWB_PER_PHASE`` in any cell — the regression
+gate CI runs via ``--smoke``.
 """
 
 from __future__ import annotations
@@ -42,6 +43,17 @@ _ROOT = Path(__file__).resolve().parent.parent  # repo root, CWD-independent
 # pure single-side op codes per kind: (tail-side push, head-side pop)
 _TAIL_PUSH = {"queue": 1, "deque": 3}   # enq / pushr
 _HEAD_POP = {"queue": 2, "deque": 2}    # deq / popl
+
+# exact steady-state pwbs per measured phase, (one-lane, two-lane), at any
+# batch size: 3 announce + 1 response + 2 epoch writes, plus the slot files
+# (one-lane: manifest and, when dirty, values; two-lane: the committing
+# lanes' rec.json and the values they own, when dirty)
+PWB_PER_PHASE = {
+    ("queue", "skewed"): (8.0, 7.5),
+    ("queue", "drained"): (7.0, 8.0),
+    ("deque", "skewed"): (8.0, 8.0),
+    ("deque", "drained"): (8.0, 10.0),
+}
 
 
 def _schedule(kind: str, skew: str, m: int, phases: int):
@@ -163,19 +175,19 @@ if __name__ == "__main__":
         from bench_common import write_rows
     write_rows(args.out, rows, extra={"entry": "script", "smoke": args.smoke})
     print(f"# wrote {args.out} ({len(rows)} cells)")
-    # regression gate: on skewed arrivals the two-lane layout must pay
-    # strictly FEWER pwb/op than the one-lane layout
-    losers = [
-        r for r in rows
-        if r["skew"] == "skewed"
-        and r["two_lane_pwb_per_op"] >= r["one_lane_pwb_per_op"]
-    ]
+    # regression gate: each layout pays exactly its pinned pwbs per phase
+    losers = []
+    for r in rows:
+        ops_per_phase = r["batch"] * (1 if r["skew"] == "skewed" else 2)
+        got = tuple(round(r[f"{t}_pwb_per_op"] * ops_per_phase, 6)
+                    for t in ("one_lane", "two_lane"))
+        if got != PWB_PER_PHASE[(r["kind"], r["skew"])]:
+            losers.append((r, got))
     if losers:
-        for r in losers:
+        for r, got in losers:
             print(
-                f"# REGRESSION {r['kind']}/{r['skew']}: two-lane "
-                f"{r['two_lane_pwb_per_op']:.3f} >= one-lane "
-                f"{r['one_lane_pwb_per_op']:.3f} pwb/op",
+                f"# REGRESSION {r['kind']}/{r['skew']}: (one-lane, two-lane) "
+                f"pwb/phase {got} != {PWB_PER_PHASE[(r['kind'], r['skew'])]}",
                 file=sys.stderr,
             )
         sys.exit(1)

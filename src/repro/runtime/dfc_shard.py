@@ -107,7 +107,11 @@ the combiner's durable writes with the collection of the next batch):
 Persistence layout (``SimFS``-backed, pwb=write / pfence=fsync):
 
   tAnn/thread_{t}/ann{0,1}.json   double-buffered announcements + valid
-  shard_{s}/slot{0,1}/...         alternating state slots, picked by parity
+  shard_{s}/slot{0,1}/meta.json   alternating state slots, picked by parity:
+                                  the manifest, small leaves inline (ends,
+                                  size, count, epoch as raw bytes)
+  shard_{s}/slot{0,1}/leaf_*.npy  capacity-sized leaves only (values; the
+                                  map's keys, values, occupied)
   shard_{s}/cEpoch                per-shard two-increment commit
   routing/slot{0,1}.json          alternating routing records
   routing/rEpoch                  routing-epoch two-increment commit
@@ -117,6 +121,7 @@ Persistence layout (``SimFS``-backed, pwb=write / pfence=fsync):
 
 from __future__ import annotations
 
+import base64
 import collections
 import dataclasses
 import functools
@@ -186,6 +191,15 @@ def _fetch(tree):
     return out
 
 
+def _slot_leaf(fs: SimFS, slot: str, entry: Dict[str, Any]) -> np.ndarray:
+    """One leaf of a durable slot from its ``meta.json`` entry: the
+    ``leaf_{i}.npy`` file the entry names, or its inline raw bytes."""
+    if "file" in entry:
+        return np.load(io.BytesIO(fs.read_durable(f"{slot}/{entry['file']}")))
+    raw = base64.b64decode(entry["data"])
+    return np.frombuffer(raw, dtype=entry["dtype"]).reshape(entry["shape"])
+
+
 # ---------------------------------------------------------------------------
 # Per-side combiners (ISSUE 8, after Persistent Software Combining 2107.03492
 # and Highly-Efficient Persistent FIFO Queues 2402.17674): with
@@ -210,12 +224,12 @@ def _fetch(tree):
 # it (before the fsync: both lanes roll back together; after: both round up).
 #
 # ``values`` ownership per lane: the queue's head lane never writes values
-# (pops only advance the head counter), so its record is a single tiny JSON —
-# that asymmetry is the pwb/op win the jitter test pins.  The deque's LEFT
-# side pushes into values too, so both deque lane records carry values (with
-# dirty-leaf elision); recovery picks the values of the lane whose record
-# carries the larger ``phases`` counter (a per-shard commit sequence number),
-# which is the chronologically last committed copy.
+# (pops only advance the head counter), so its record is a single tiny JSON;
+# the jitter test pins what that saves against the one-lane slot.  The
+# deque's LEFT side pushes into values too, so both deque lane records carry
+# values (with dirty-leaf elision); recovery picks the values of the lane
+# whose record carries the larger ``phases`` counter (a per-shard commit
+# sequence number), which is the chronologically last committed copy.
 _LANE_WRITES_VALUES = {"queue": (False, True), "deque": (True, True)}
 _LANE_TAGS = ("H", "T")  # indexed by LANE_HEAD / LANE_TAIL
 
@@ -1194,15 +1208,22 @@ class ShardedDFCRuntime:
         """pwb shard ``s``'s post-combine (or explicitly given) state into
         its inactive slot.
 
+        The slot is one ``meta.json`` manifest plus a ``leaf_{i}.npy`` file
+        for each leaf whose leading dimension is the shard's capacity (the
+        table arrays).  Every other leaf (``ends``, ``size``, ``count``,
+        ``epoch``) is small and rides inline in the manifest as its raw
+        bytes, so a touched queue shard costs two file writes, not four:
+        on the store, files written set the phase fence's cost, not bytes.
+
         Dirty-leaf elision (the paper's dirty-word tracking, at leaf
-        granularity): a leaf whose bytes are identical to what this slot
-        already holds DURABLY is skipped — its file is still listed in the
-        slot manifest and still readable at recovery, so crash consistency
-        is unchanged, but a combining phase that only moved root counters
-        (e.g. a fully-eliminating stack batch, or a queue batch served
-        entirely from the committed ring window) stops re-persisting the
-        whole ``values`` array.  Digests are promoted into the elision cache
-        only after the phase's pfence (``_promote_elision``).
+        granularity): a file leaf whose bytes are identical to what this
+        slot already holds DURABLY is skipped — its file is still listed in
+        the slot manifest and still readable at recovery, so crash
+        consistency is unchanged, but a combining phase that only moved root
+        counters (e.g. a fully-eliminating stack batch, or a queue batch
+        served entirely from the committed ring window) stops re-persisting
+        the whole ``values`` array.  Digests are promoted into the elision
+        cache only after the phase's pfence (``_promote_elision``).
         """
         one = self._shard_state(s) if state is None else state
         slot = self._slot_dir(s, epoch_target - 2, nxt=True)
@@ -1220,16 +1241,21 @@ class ShardedDFCRuntime:
             "phases": int(counters[0]),
             "ops_combined": int(counters[1]),
         }
+        inlined = 0
         with self.obs.span("retire.serialize"):
             for i, leaf in enumerate(leaves):
                 arr = np.asarray(leaf)
-                rel = f"{slot}/leaf_{i}.npy"
-                if self._save_leaf(rel, arr):
-                    files.append(rel)
-                meta["leaves"].append(
-                    {"file": f"leaf_{i}.npy", "shape": list(arr.shape), "dtype": str(arr.dtype)}
-                )
-        self._count_leaves(s, len(files), len(leaves) - len(files))
+                entry = {"shape": list(arr.shape), "dtype": str(arr.dtype)}
+                if arr.ndim and arr.shape[0] == self.capacity:
+                    entry["file"] = f"leaf_{i}.npy"
+                    rel = f"{slot}/{entry['file']}"
+                    if self._save_leaf(rel, arr):
+                        files.append(rel)
+                else:
+                    entry["data"] = base64.b64encode(arr.tobytes()).decode()
+                    inlined += 1
+                meta["leaves"].append(entry)
+        self._count_leaves(s, len(files), len(leaves) - len(files) - inlined, inlined)
         rel = f"{slot}/meta.json"
         self.fs.write(rel, json.dumps(meta).encode(), tag="slot")
         files.append(rel)
@@ -1248,15 +1274,18 @@ class ShardedDFCRuntime:
         self._elide_pending[rel] = digest
         return True
 
-    def _count_leaves(self, s: int, written: int, elided: int) -> None:
-        """Slot leaves written and elided for shard ``s``: the profiler
-        tally's ``slot_leaves_written`` / ``slot_leaves_elided``, and the
-        observer's per-shard counters of the same names."""
+    def _count_leaves(self, s: int, written: int, elided: int, inlined: int = 0) -> None:
+        """Slot leaves written, elided and inlined into the manifest for
+        shard ``s``: the profiler tally's ``slot_leaves_written`` /
+        ``slot_leaves_elided`` / ``slot_leaves_inlined``, and the observer's
+        per-shard counters of the same names."""
         count("slot_leaves_written", written)
         count("slot_leaves_elided", elided)
+        count("slot_leaves_inlined", inlined)
         if self.obs.enabled:
             self.obs.metrics.counter("slot_leaves_written", written, shard=s)
             self.obs.metrics.counter("slot_leaves_elided", elided, shard=s)
+            self.obs.metrics.counter("slot_leaves_inlined", inlined, shard=s)
 
     def _promote_elision(self) -> None:
         """Move leaf digests written since the last pfence into the elision
@@ -1346,8 +1375,7 @@ class ShardedDFCRuntime:
         inactive lane slots (the split twin of ``_persist_shard``).
 
         Only the committing lane(s) write: a head-side queue phase writes ONE
-        tiny ``rec.json`` — no values leaf, no ends leaf, no epoch leaf —
-        which is where the two-lane pwb/op win comes from.  Lanes that own
+        tiny ``rec.json`` and no values leaf.  Lanes that own
         values writes (``_LANE_WRITES_VALUES``) persist ``values.npy`` with
         the same dirty-leaf digest elision as the one-lane path, so a phase
         that only moved counters (drained elimination, window-served pops)
@@ -2497,11 +2525,10 @@ class ShardedDFCRuntime:
                 live = {f"{active}/meta.json"}
                 if meta_raw:
                     meta = json.loads(meta_raw.decode())
-                    live |= {f"{active}/{e['file']}" for e in meta["leaves"]}
-                    leaves = [
-                        np.load(io.BytesIO(fs.read_durable(f"{active}/{e['file']}")))
-                        for e in meta["leaves"]
-                    ]
+                    live |= {
+                        f"{active}/{e['file']}" for e in meta["leaves"] if "file" in e
+                    }
+                    leaves = [_slot_leaf(fs, active, e) for e in meta["leaves"]]
                     treedef = jax.tree_util.tree_structure(fresh)
                     shard_states.append(
                         jax.tree_util.tree_unflatten(

@@ -120,8 +120,9 @@ def test_session_records_every_span_nested_and_the_bytes_fetched(tmp_path, monke
         assert spans[child][1] <= spans[parent][1]
     assert totals["counters"]["d2h_bytes"] == sum(fetched) > 0
     touched = spans["retire.serialize"][0]
-    assert totals["counters"]["slot_leaves_written"] + totals["counters"][
-        "slot_leaves_elided"] == touched * len(jax.tree_util.tree_leaves(rt._shard_state(0)))
+    counters = totals["counters"]
+    assert counters["slot_leaves_written"] + counters["slot_leaves_elided"] + counters[
+        "slot_leaves_inlined"] == touched * len(jax.tree_util.tree_leaves(rt._shard_state(0)))
 
     # in the trace, every child lies inside an event of its parent's name on
     # the same host thread
