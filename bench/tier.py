@@ -1,12 +1,14 @@
 """Driver of the serving tier (``system: tier``): an open loop of session
 arrivals through ``RequestQueueTier``, with no model behind it.
 
-Sessions are due at seeded times.  Each iteration submits every session that
-is due, admits queued sessions up to the free decode slots, marks each
-admitted session served and gives its slot back; batches are powers of two
-(see ``_iteration``).  Admission latency runs from a session's due time to the
-``admit`` call that returns it; that admission is durable (its dequeue and
-its ADMITTED state are committed).
+Sessions are due at seeded times.  Each iteration is one scheduling round of
+``ContinuousServer.run`` (``launch/serve.py``) with zero decode time: submit
+the retried and the due sessions, at most ``max_submit`` to a call; admit up
+to the free decode slots; mark each admitted session served; give the slots
+back in one submit.  One op is one durable admission: ``ops_per_s`` counts
+the window's admissions over its seconds, and ``p95_ms`` runs from a
+session's due time to the ``admit`` call that returns it, once its dequeue
+and its ADMITTED state are committed.
 
 Every fabric phase the tier runs is logged at the fabric's entry points
 (``announce`` and ``read_responses``) and replayed through the reference
@@ -91,21 +93,32 @@ class Driver:
 
         rt.announce, rt.read_responses = logged_announce, logged_read
 
+    def max_phase(self) -> int:
+        """The most operations one phase of the loop announces.  A submit
+        phase carries its sessions' enqueues and their session-map inserts,
+        two to a session; an admission pops and binds up to ``slots`` and a
+        release pushes as many.  Retried slots and states would ride on top,
+        but they only follow an ``R_OVERFLOW``, which no phase meets while
+        no shard gets more than ``lanes`` of its operations."""
+        return max(2 * self.max_submit, self.slots)
+
     def _warm_shapes(self) -> None:
-        """Compile every phase size the loop can dispatch: the loop submits
-        and admits in power-of-two batches, so a phase holds 2^k operations
-        (submits hold two per session), up to twice ``max_submit``."""
+        """Compile every phase size the loop can announce, 1 to
+        :meth:`max_phase`, and the fused step at each padded width."""
         from repro.runtime.dfc_shard import ShardedDFCRuntime
 
+        if max(self.max_submit, self.slots) > self.lanes:
+            raise ValueError(f"max_submit {self.max_submit} and slots {self.slots} "
+                             f"must fit a shard's {self.lanes} lanes: a phase that "
+                             "overflows retries its surplus in larger phases")
         rt = self.tier.rt
         scratch = ShardedDFCRuntime(
             rt.kinds, rt.n_shards, self.lanes, self.lanes, n_buckets=rt.n_buckets,
             table=rt.table, fs=durable.counting_fs(self.ctx.store / "scratch"),
             n_threads=1,
         )
-        sizes = [1 << k for k in range((2 * self.max_submit).bit_length())]
-        durable.warm_batches(scratch, [[n] for n in sizes])
-        durable.warm_step(rt, durable.pad_sizes(sizes[-1]))
+        durable.warm_batches(scratch, [[n] for n in range(1, self.max_phase() + 1)])
+        durable.warm_step(rt, durable.pad_sizes(self.max_phase()))
 
     # ------------------------------------------------------------ the loop
     def _queued(self) -> int:
@@ -122,17 +135,16 @@ class Driver:
                 return
 
     def _iteration(self) -> bool:
-        """Submit what is due, admit, serve, release; returns whether it did
-        any work.  Batches are powers of two, so every phase has a size that
-        set-up compiled: the due sessions go in chunks of the largest power
-        of two that fits (at most ``max_submit``), and one admission takes
-        the largest power of two of queued sessions that the slots hold."""
+        """One round of ``ContinuousServer.run`` with zero decode time:
+        submit the retried sessions, then the due ones, at most
+        ``max_submit`` to a call; if sessions are queued, admit up to the
+        free decode slots, mark each admitted session served and release
+        their slots in one submit.  Returns whether it did any work."""
         span = self.ctx.spans
         batch, self.waiting = self.waiting, []
         worked = bool(batch)
-        while batch:
-            k = 1 << (min(len(batch), self.max_submit).bit_length() - 1)
-            chunk, batch = batch[:k], batch[k:]
+        for i in range(0, len(batch), self.max_submit):
+            chunk = batch[i:i + self.max_submit]
             t = time.perf_counter()
             self.late += [t - self.due[s] for s in chunk if s not in self.late_seen]
             self.late_seen.update(chunk)
@@ -140,11 +152,13 @@ class Driver:
                 rejected = set(self.tier.submit(chunk))
             self.accepted += [s for s in chunk if s not in rejected]
             self.waiting += [s for s in chunk if s in rejected]
-        queued = self._queued()
-        if queued:
+        if self._queued():
             worked = True
+            # the slots no admitted, unserved session holds, as
+            # ContinuousServer counts them: all, with zero decode time
+            free = self.slots - (len(self.admitted) - len(self.served))
             with span("admit"):
-                pairs = self.tier.admit(1 << (min(self.slots, queued).bit_length() - 1))
+                pairs = self.tier.admit(free)
             t = time.perf_counter()
             for sid, _ in pairs:
                 self.admit_t[sid] = t
@@ -270,8 +284,8 @@ class Driver:
     # ------------------------------------------------------------ metrics
     def end_to_end(self) -> Dict[str, float]:
         waits = [self.admit_t[s] - self.due[s] for s in self.window_sids if s in self.admit_t]
-        return {"admit_p95_ms": harness.percentile(waits, 95) * 1e3,
-                "sessions_per_s": self.window_admitted / self.elapsed}
+        return {"p95_ms": harness.percentile(waits, 95) * 1e3,
+                "ops_per_s": self.window_admitted / self.elapsed}
 
     def record(self, trace):
         return durable.record(

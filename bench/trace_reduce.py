@@ -118,6 +118,37 @@ def read_events(path) -> Dict[str, object]:
     return {"devices": devices, "host": host}
 
 
+def charge_gaps(idle_gaps: Sequence[Interval], spans, idle: collections.Counter) -> None:
+    """Charge each idle gap to the host spans open in it, into ``idle``.
+
+    Within a gap the spans that overlap it are charged shortest first (the
+    innermost call the host was in; equal lengths in ``spans``' order), each
+    with its overlap but never more than is left of the gap; what no span
+    covers goes to ``host:other``.  Gaps are sorted and disjoint, so one
+    sweep over the spans sorted by start finds each gap's spans: a span
+    enters when it starts before the gap ends and leaves once it ends before
+    a gap starts."""
+    by_start = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    nxt, live = 0, []
+    for ga, gb in idle_gaps:
+        while nxt < len(by_start) and spans[by_start[nxt]][1] < gb:
+            live.append(by_start[nxt])
+            nxt += 1
+        live = [i for i in live if spans[i][2] > ga]
+        left = gb - ga
+        for i in sorted(live, key=lambda i: (spans[i][2] - spans[i][1], i)):
+            if left <= 0:
+                break
+            name, a, b = spans[i]
+            cover = min(b, gb) - max(a, ga)
+            if cover > 0:
+                cover = min(cover, left)
+                idle[name] += cover
+                left -= cover
+        if left > 0:
+            idle["host:other"] += left
+
+
 def reduce_events(events, span_names: Sequence[str], top: int = 10) -> Dict[str, object]:
     """Reduce :func:`read_events` output; ``span_names`` are the benchmark's
     host spans that idle gaps are attributed to (innermost first)."""
@@ -126,9 +157,8 @@ def reduce_events(events, span_names: Sequence[str], top: int = 10) -> Dict[str,
         raise ValueError(f"expected one '{WINDOW_SPAN}' host span, found {len(windows)}")
     lo, hi = windows[0]
     window_ns = hi - lo
-    # shortest first: a gap goes to the innermost call the host was in
-    spans = sorted(((n, a, b) for n, a, b in events["host"] if n in set(span_names)),
-                   key=lambda s: s[2] - s[1])
+    names = set(span_names)
+    spans = [(n, a, b) for n, a, b in events["host"] if n in names]
     busy_per_device, programs, op_time = [], collections.Counter(), collections.Counter()
     idle: collections.Counter = collections.Counter()
     for lines in events["devices"].values():
@@ -143,18 +173,7 @@ def reduce_events(events, span_names: Sequence[str], top: int = 10) -> Dict[str,
         for name, a, b in lines.get(MODULES_LINE, []):
             for ca, cb in clip([(a, b)], lo, hi):
                 programs[program_name(name)] += cb - ca
-        for ga, gb in gaps(busy, lo, hi):
-            left = gb - ga
-            for name, a, b in spans:
-                if left <= 0:
-                    break
-                cover = total(clip([(a, b)], ga, gb))
-                if cover > 0:
-                    cover = min(cover, left)
-                    idle[name] += cover
-                    left -= cover
-            if left > 0:
-                idle["host:other"] += left
+        charge_gaps(gaps(busy, lo, hi), spans, idle)
     if not busy_per_device:
         return {"busy_s": 0.0, "window_s": window_ns * 1e-9, "programs": {},
                 "device_ops": [], "idle_gaps": []}
